@@ -669,54 +669,6 @@ class TestDeviceSearch:
 
 
 # ---------------------------------------------------------------------------
-# real-chip gate (compiles the Pallas lowering on hardware; skips off-TPU)
-# ---------------------------------------------------------------------------
-
-class TestGenDeviceRealChip:
-    """Compile (not just interpret) the lowered Pallas kernels when a
-    real TPU is reachable — the standing hardware gate alongside
-    TestRingDmaRealChip. A 1-chip mesh compiles the kernel scaffolding;
-    multi-chip compiles the remote-DMA layer schedule itself."""
-
-    @staticmethod
-    def _tpus():
-        tpus = [d for d in jax.devices()
-                if d.platform not in ("cpu",)]
-        if not tpus:
-            pytest.skip("no TPU devices reachable")
-        if len(tpus) < 2:
-            pytest.skip("device lowering needs >= 2 chips")
-        return tpus
-
-    @pytest.mark.parametrize("family,param", [
-        ("ring", 1), ("ring", 2), ("rhd", 0), ("bc_kn", 0),
-        ("bc_chain", 2)])
-    def test_compiles_on_tpu(self, family, param):
-        tpus = self._tpus()
-        from ucc_tpu.dsl.lower_device import build_device_program
-        from ucc_tpu.dsl.registry import build_program
-        n = len(tpus)
-        prog = build_program(family, param, n)
-        if prog is None:
-            pytest.skip(f"{family}({param}) inapplicable at n={n}")
-        mesh = jax.sharding.Mesh(np.array(tpus), ("r",))
-        count = 128 * prog.nchunks
-        op = ReductionOp.SUM
-        program, padded = build_device_program(
-            mesh, prog, n, count, op, np.dtype(np.float32), 0,
-            "pallas", 256, "")
-        assert padded == count
-        from jax.sharding import NamedSharding, PartitionSpec as P
-        shards = [jax.device_put(jnp.ones(count, jnp.float32), d)
-                  for d in tpus]
-        garr = jax.make_array_from_single_device_arrays(
-            (n * count,), NamedSharding(mesh, P("r")), shards)
-        out = np.asarray(jax.block_until_ready(program(garr)))
-        if prog.coll == CollType.ALLREDUCE:
-            np.testing.assert_allclose(out[:count], float(n))
-
-
-# ---------------------------------------------------------------------------
 # device-side stragglers feed the continuous scorer (ISSUE 16)
 # ---------------------------------------------------------------------------
 

@@ -11,13 +11,6 @@ import jax.numpy as jnp  # noqa: E402
 from jax.sharding import PartitionSpec as P  # noqa: E402
 
 
-def get_shard_map():
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map
-    from jax.experimental.shard_map import shard_map
-    return shard_map
-
-
 @pytest.fixture(scope="module")
 def mesh():
     if len(jax.devices()) < 8:
@@ -26,13 +19,8 @@ def mesh():
 
 
 def run_sm(mesh, fn, x, out_specs=P("r", None)):
-    sm = get_shard_map()
-    try:
-        wrapped = sm(fn, mesh=mesh, in_specs=P("r", None),
-                     out_specs=out_specs, check_vma=False)
-    except TypeError:
-        wrapped = sm(fn, mesh=mesh, in_specs=P("r", None),
-                     out_specs=out_specs, check_rep=False)
+    wrapped = jax.shard_map(fn, mesh=mesh, in_specs=P("r", None),
+                            out_specs=out_specs, check_vma=False)
     return jax.jit(wrapped)(x)
 
 
@@ -95,18 +83,13 @@ class TestOpsInJit:
     def test_composes_with_grad(self, mesh):
         """ops inside a differentiated program — the data-parallel
         gradient-sync use case (psum is linear, grad flows)."""
-        sm = get_shard_map()
-
         def loss(w, x):
             def shard_fn(w, x):
                 local = jnp.sum((x @ w) ** 2, keepdims=True)[None]
                 return ops.allreduce(local, ReductionOp.SUM)
-            try:
-                f = sm(shard_fn, mesh=mesh, in_specs=(P(), P("r", None)),
-                       out_specs=P(None, None), check_vma=False)
-            except TypeError:
-                f = sm(shard_fn, mesh=mesh, in_specs=(P(), P("r", None)),
-                       out_specs=P(None, None), check_rep=False)
+            f = jax.shard_map(shard_fn, mesh=mesh,
+                              in_specs=(P(), P("r", None)),
+                              out_specs=P(None, None), check_vma=False)
             return f(w, x)[0, 0]
 
         w = jnp.ones((4,), jnp.float32)
@@ -123,7 +106,6 @@ class TestOpsAlltoallv:
         import jax
         import jax.numpy as jnp
         from jax.sharding import NamedSharding, PartitionSpec as P
-        from ucc_tpu.utils.jaxshim import shard_map_compat
         n = min(8, len(jax.devices()))
         if n < 2:
             pytest.skip("needs >= 2 devices")
@@ -143,8 +125,9 @@ class TestOpsAlltoallv:
             [jax.device_put(jnp.asarray(srcs[i]), mesh.devices.reshape(-1)[i])
              for i in range(n)])
 
-        prog = jax.jit(shard_map_compat(
-            lambda x: ops.alltoallv(x, m), mesh, P("r"), P("r")))
+        prog = jax.jit(jax.shard_map(
+            lambda x: ops.alltoallv(x, m), mesh=mesh, in_specs=P("r"),
+            out_specs=P("r"), check_vma=False))
         out = prog(garr)
         shards = {s.device: np.asarray(s.data)
                   for s in out.addressable_shards}
@@ -164,7 +147,6 @@ class TestOpsAlltoallv:
         import jax
         import jax.numpy as jnp
         from jax.sharding import NamedSharding, PartitionSpec as P
-        from ucc_tpu.utils.jaxshim import shard_map_compat
         n = min(8, len(jax.devices()))
         if n < 2:
             pytest.skip("needs >= 2 devices")
@@ -181,8 +163,9 @@ class TestOpsAlltoallv:
             [jax.device_put(jnp.asarray(srcs[i]),
                             mesh.devices.reshape(-1)[i])
              for i in range(n)])
-        prog = jax.jit(shard_map_compat(
-            lambda x: ops.allgatherv(x, counts), mesh, P("r"), P(None)))
+        prog = jax.jit(jax.shard_map(
+            lambda x: ops.allgatherv(x, counts), mesh=mesh,
+            in_specs=P("r"), out_specs=P(None), check_vma=False))
         out = np.asarray(prog(garr))
         expect = np.concatenate(
             [np.arange(counts[i], dtype=np.int32) + 10 * i

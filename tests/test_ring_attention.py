@@ -71,7 +71,7 @@ class TestFusedRingFlashAttention:
     """The Pallas-fused tier (ucc_tpu/fused_attention.py): K/V rotation
     as in-kernel remote DMAs overlapping the flash block update —
     validated exactly against full softmax(QK^T)V (interpret mode on the
-    CPU mesh; the compiled ICI path shares ring_dma's hardware gate)."""
+    CPU mesh; tests/test_tpu_compile.py compiles the Mosaic path)."""
 
     @pytest.mark.parametrize("causal", [False, True])
     def test_exact_vs_reference(self, mesh, causal):
@@ -129,7 +129,6 @@ class TestFusedRingFlashAttention:
         import contextlib
         from jax.sharding import NamedSharding, PartitionSpec as P
         from ucc_tpu.fused_attention import ring_flash_attention
-        from ucc_tpu.utils.jaxshim import shard_map_compat
         heads, seq, d = 2, 24, 4
         q, k, v = _inputs(heads, seq, d, seed=9)
         sh = NamedSharding(mesh, P(None, "sp", None))
@@ -138,8 +137,9 @@ class TestFusedRingFlashAttention:
         def body(a, b, c):
             return ring_flash_attention(a, b, c, axis_name="sp",
                                         causal=causal)
-        f = shard_map_compat(body, mesh, (P(None, "sp", None),) * 3,
-                             P(None, "sp", None))
+        f = jax.shard_map(body, mesh=mesh,
+                          in_specs=(P(None, "sp", None),) * 3,
+                          out_specs=P(None, "sp", None), check_vma=False)
 
         @jax.jit
         def loss(a, b, c):
@@ -240,22 +240,23 @@ class TestLongContextTraining:
         the lax ring schedule; results must match the 1-axis fused path."""
         from jax.sharding import NamedSharding, PartitionSpec as P
         from ucc_tpu.fused_attention import ring_flash_attention
-        from ucc_tpu.utils.jaxshim import shard_map_compat
         heads, seq, d = 2, 32, 8
         q, k, v = _inputs(heads, seq, d, seed=12)
         # 1-axis fused
         sh1 = NamedSharding(mesh, P(None, "sp", None))
-        f1 = shard_map_compat(
+        f1 = jax.shard_map(
             lambda a, b, c: ring_flash_attention(a, b, c, axis_name="sp"),
-            mesh, (P(None, "sp", None),) * 3, P(None, "sp", None))
+            mesh=mesh, in_specs=(P(None, "sp", None),) * 3,
+            out_specs=P(None, "sp", None), check_vma=False)
         out1 = np.asarray(jax.device_get(jax.jit(f1)(
             *(jax.device_put(t, sh1) for t in (q, k, v)))))
         # 2-axis mesh (fallback path), sp size 4
         mesh2 = jax.make_mesh((2, 4), ("dp", "sp"))
         sh2 = NamedSharding(mesh2, P(None, "sp", None))
-        f2 = shard_map_compat(
+        f2 = jax.shard_map(
             lambda a, b, c: ring_flash_attention(a, b, c, axis_name="sp"),
-            mesh2, (P(None, "sp", None),) * 3, P(None, "sp", None))
+            mesh=mesh2, in_specs=(P(None, "sp", None),) * 3,
+            out_specs=P(None, "sp", None), check_vma=False)
         out2 = np.asarray(jax.device_get(jax.jit(f2)(
             *(jax.device_put(t, sh2) for t in (q, k, v)))))
         np.testing.assert_allclose(out1, out2, rtol=2e-5, atol=2e-6)
@@ -305,14 +306,14 @@ class TestGroupedQueryAttention:
     def test_mismatched_heads_rejected(self, mesh):
         from jax.sharding import NamedSharding, PartitionSpec as P
         from ucc_tpu.fused_attention import ring_flash_attention
-        from ucc_tpu.utils.jaxshim import shard_map_compat
         q, k, v = self._gqa_inputs(5, 2, 16, 4)   # 5 % 2 != 0
         sh = NamedSharding(mesh, P(None, "sp", None))
 
         def body(a, b, c):
             return ring_flash_attention(a, b, c, axis_name="sp")
-        f = shard_map_compat(body, mesh, (P(None, "sp", None),) * 3,
-                             P(None, "sp", None))
+        f = jax.shard_map(body, mesh=mesh,
+                          in_specs=(P(None, "sp", None),) * 3,
+                          out_specs=P(None, "sp", None), check_vma=False)
         with pytest.raises(ValueError, match="GQA"):
             f(*(jax.device_put(x, sh) for x in (q, k, v)))
 
@@ -324,7 +325,6 @@ class TestGroupedQueryAttention:
         import contextlib
         from jax.sharding import NamedSharding, PartitionSpec as P
         from ucc_tpu.fused_attention import ring_flash_attention
-        from ucc_tpu.utils.jaxshim import shard_map_compat
         h, h_kv, seq, d = 4, 2, 24, 4
         q, k, v = self._gqa_inputs(h, h_kv, seq, d, seed=23)
         sh = NamedSharding(mesh, P(None, "sp", None))
@@ -333,8 +333,9 @@ class TestGroupedQueryAttention:
         def body(a, b, c):
             return ring_flash_attention(a, b, c, axis_name="sp",
                                         causal=causal)
-        f = shard_map_compat(body, mesh, (P(None, "sp", None),) * 3,
-                             P(None, "sp", None))
+        f = jax.shard_map(body, mesh=mesh,
+                          in_specs=(P(None, "sp", None),) * 3,
+                          out_specs=P(None, "sp", None), check_vma=False)
 
         @jax.jit
         def loss(a, b, c):

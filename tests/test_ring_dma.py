@@ -158,86 +158,6 @@ class TestRingDmaDataMovement:
                 expect[off:off + block_count(total, N, r)])
 
 
-class TestRingDmaRealChip:
-    """Compile (not just interpret) every ring_dma kernel family when a
-    real TPU is reachable; skipped on the CPU mesh. A 1-chip mesh
-    compiles the kernel scaffolding (and must: degenerate n=1 scratch /
-    barriers lower too); multi-chip compiles the DMA ring itself.
-    Parametrized per builder so the probe capture log shows exactly
-    which kernel family fails on hardware."""
-
-    @staticmethod
-    def _tpus():
-        tpus = [d for d in jax.devices() if d.platform not in ("cpu",)]
-        if not tpus:
-            pytest.skip("no TPU devices reachable")
-        return tpus
-
-    @pytest.mark.parametrize("family", [
-        "ring_allreduce", "ring_allgather", "ring_reduce_scatter",
-        "bcast", "hbm_allreduce", "hbm_allgather", "hbm_reduce_scatter",
-        "alltoall", "hbm_bcast", "hbm_alltoall"])
-    def test_compiles_on_tpu(self, family):
-        tpus = self._tpus()
-        from jax.sharding import NamedSharding, PartitionSpec as P
-        from ucc_tpu.tl import ring_dma as rd
-        n = len(tpus)
-        mesh = jax.sharding.Mesh(np.array(tpus), ("r",))
-        f32 = np.dtype(np.float32)
-        builder = {
-            "ring_allreduce": lambda: rd.build_ring_program(
-                mesh, n, CollType.ALLREDUCE, ReductionOp.SUM, f32,
-                128 * n),
-            "ring_allgather": lambda: rd.build_ring_program(
-                mesh, n, CollType.ALLGATHER, None, f32, 128),
-            "ring_reduce_scatter": lambda: rd.build_ring_program(
-                mesh, n, CollType.REDUCE_SCATTER, ReductionOp.SUM, f32,
-                128 * n),
-            "bcast": lambda: rd.build_bcast_program(mesh, n, 0, f32,
-                                                    4096),
-            "hbm_allreduce": lambda: rd.build_hbm_allreduce_program(
-                mesh, n, ReductionOp.SUM, f32, rd.CHUNK_ELEMS * 2),
-            "hbm_allgather": lambda: rd.build_hbm_allgather_program(
-                mesh, n, f32, rd.CHUNK_ELEMS * 2),
-            "hbm_reduce_scatter": lambda:
-                rd.build_hbm_reduce_scatter_program(
-                    mesh, n, ReductionOp.SUM, f32, rd.CHUNK_ELEMS * 2 * n),
-            "alltoall": lambda: rd.build_alltoall_program(mesh, n, f32,
-                                                          128 * n),
-            "hbm_bcast": lambda: rd.build_hbm_bcast_program(
-                mesh, n, 0, f32, rd.CHUNK_ELEMS * 2),
-            "hbm_alltoall": lambda: rd.build_hbm_alltoall_program(
-                mesh, n, f32, rd.CHUNK_ELEMS * 2 * n),
-        }[family]
-        program, padded = builder()
-        garr = jax.make_array_from_single_device_arrays(
-            (n * padded,), NamedSharding(mesh, P("r")),
-            [jax.device_put(jnp.ones((padded,), jnp.float32), d)
-             for d in tpus])
-        assert program.lower(garr).compile() is not None
-
-    @pytest.mark.parametrize("mesh_shape", ["1d", "dp_sp"])
-    def test_fused_attention_compiles_on_tpu(self, mesh_shape):
-        """The fused ring flash-attention kernel shares ring_dma's
-        slot/ack protocol — same hardware gate. dp_sp compiles the
-        MULTI-AXIS path (dict MESH device ids over the sp axis of a
-        ('dp','sp') mesh — round-4 lift of the lax-only fallback)."""
-        tpus = self._tpus()
-        n = len(tpus)
-        from jax.sharding import NamedSharding, PartitionSpec as P
-        from ucc_tpu.fused_attention import make_ring_flash_attention
-        if mesh_shape == "1d":
-            mesh = jax.sharding.Mesh(np.array(tpus), ("sp",))
-        else:
-            mesh = jax.sharding.Mesh(np.array(tpus).reshape(1, n),
-                                     ("dp", "sp"))
-        prog = make_ring_flash_attention(mesh, causal=True, axis="sp")
-        h, s_loc, d = 2, 128, 128
-        sh = NamedSharding(mesh, P(None, "sp", None))
-        q = jax.device_put(jnp.ones((h, n * s_loc, d), jnp.bfloat16), sh)
-        assert prog.lower(q, q, q).compile() is not None
-
-
 class TestRingDmaChunked:
     """Vectors beyond one VMEM working set split into independent ring
     passes; results must reassemble exactly per mode."""
@@ -247,6 +167,7 @@ class TestRingDmaChunked:
     def test_chunked_paths(self, job, teams, coll, count, monkeypatch):
         from ucc_tpu.tl import ring_dma as rd
         monkeypatch.setattr(rd, "CHUNK_ELEMS", 8)   # force several chunks
+        monkeypatch.setattr(rd, "TILE_BYTES", 4)   # 1-element f32 tiles
         ct = {"allreduce": CollType.ALLREDUCE,
               "allgather": CollType.ALLGATHER,
               "reduce_scatter": CollType.REDUCE_SCATTER}[coll]
@@ -347,6 +268,7 @@ class TestRingDmaBcast:
         from ucc_tpu.tl.ring_dma import build_bcast_program
         from jax.sharding import NamedSharding, PartitionSpec as P
         monkeypatch.setattr(rd, "CHUNK_ELEMS", 64)
+        monkeypatch.setattr(rd, "TILE_BYTES", 4)   # 1-element f32 tiles
         n = 4
         mesh = jax.make_mesh((n,), ("r",))
         prog, padded = build_bcast_program(mesh, n, 1,
@@ -373,6 +295,7 @@ class TestRingDmaHbmChunked:
         from ucc_tpu.constants import ReductionOp as R
         from jax.sharding import NamedSharding, PartitionSpec as P
         monkeypatch.setattr(rd, "CHUNK_ELEMS", 64)
+        monkeypatch.setattr(rd, "TILE_BYTES", 4)   # 1-element f32 tiles
         n = 4
         mesh = jax.make_mesh((n,), ("r",))
         prog, padded = build_hbm_allreduce_program(
@@ -397,6 +320,7 @@ class TestRingDmaHbmChunked:
         import ucc_tpu.tl.ring_dma as rd
         from jax.sharding import NamedSharding, PartitionSpec as P
         monkeypatch.setattr(rd, "CHUNK_ELEMS", 64)
+        monkeypatch.setattr(rd, "TILE_BYTES", 4)   # 1-element f32 tiles
         n, count = 4, 150                      # 3 chunks of 64, pad 42
         mesh = jax.make_mesh((n,), ("r",))
         prog, padded = rd.build_hbm_allgather_program(
@@ -419,6 +343,7 @@ class TestRingDmaHbmChunked:
         from ucc_tpu.constants import ReductionOp as R
         from jax.sharding import NamedSharding, PartitionSpec as P
         monkeypatch.setattr(rd, "CHUNK_ELEMS", 64)
+        monkeypatch.setattr(rd, "TILE_BYTES", 4)   # 1-element f32 tiles
         n = 4
         blk0 = 40                              # cblk=16 -> blk_tot=48
         count = n * blk0
@@ -476,6 +401,7 @@ class TestRingDmaHbmBcastAlltoall:
         import ucc_tpu.tl.ring_dma as rd
         from jax.sharding import NamedSharding, PartitionSpec as P
         monkeypatch.setattr(rd, "CHUNK_ELEMS", 64)
+        monkeypatch.setattr(rd, "TILE_BYTES", 4)   # 1-element f32 tiles
         n = 4
         mesh = jax.make_mesh((n,), ("r",))
         prog, padded = rd.build_hbm_bcast_program(
@@ -498,6 +424,7 @@ class TestRingDmaHbmBcastAlltoall:
         import ucc_tpu.tl.ring_dma as rd
         from jax.sharding import NamedSharding, PartitionSpec as P
         monkeypatch.setattr(rd, "CHUNK_ELEMS", 64)
+        monkeypatch.setattr(rd, "TILE_BYTES", 4)   # 1-element f32 tiles
         n, blk0 = 4, 25                    # cblk=10 -> blk_tot=30
         count = n * blk0
         mesh = jax.make_mesh((n,), ("r",))
@@ -598,5 +525,5 @@ class TestRingDmaAlltoall:
         finally:
             j.cleanup()
 
-    # real-chip compile coverage lives in TestRingDmaRealChip (alltoall
+    # TPU compile coverage lives in tests/test_tpu_compile.py (alltoall
     # is one of its parametrized families)

@@ -32,8 +32,7 @@ def _run_point(msg: int, onesided: bool, window: int = 0,
     """avg latency (us) of one perftest cell, or -1 on failure."""
     env = dict(os.environ)
     env["UCC_TLS"] = "socket,self"
-    # host-memory sweep: pin the cpu platform so each child skips the
-    # (possibly wedged) accelerator probe instead of burning its timeout
+    # host-memory sweep: children stay off the chip
     env["JAX_PLATFORMS"] = "cpu"
     if window:
         env["UCC_TL_SOCKET_ALLREDUCE_SW_WINDOW"] = str(window)

@@ -99,8 +99,8 @@ class World:
         # initialize the jax backend ONCE on this thread before context
         # threads race into device discovery: cold multi-thread backend
         # init can deadlock (TL/XLA context create probes devices)
-        from .utils.jaxshim import ensure_live_backend
-        ensure_live_backend(virtual_cpu_devices=max(2, ranks_per_proc))
+        from .utils.backend import setup_backend
+        setup_backend(virtual_cpu_devices=max(2, ranks_per_proc))
 
         my_ranks = [rank * ranks_per_proc + i for i in range(ranks_per_proc)]
         self.libs = [ucc_tpu.init(lib_params) if lib_params is not None

@@ -52,9 +52,9 @@ Lowered programs register on the xla TL as score-map candidates named
 ``gen_dev_*`` with ``origin="generated-device"`` and full gen-string
 provenance (``UCC_GEN_DEVICE=y``; default off keeps candidate lists
 byte-identical). ``UCC_GEN_DEVICE_BACKEND`` picks the backend
-(``auto`` = Pallas on real TPU platforms, XLA on the CPU mesh;
-``pallas`` forces interpret-mode kernels on CPU — the test/real-chip
-gate path).
+(``auto`` = XLA; ``pallas`` forces interpret-mode kernels on the CPU
+mesh — Mosaic refuses the Pallas lowering's table-driven offsets, so a
+TPU mesh never takes it).
 """
 from __future__ import annotations
 
@@ -65,6 +65,7 @@ import numpy as np
 
 from ..constants import CollType, ReductionOp, dt_numpy
 from ..status import Status, UccError
+from ..utils.backend import is_tpu
 from ..utils.log import get_logger
 from . import families as fam
 from .ir import OpKind, Program
@@ -379,7 +380,6 @@ def _build_xla_device_program(mesh, prog: Program, n: int, count: int,
     from jax.sharding import PartitionSpec as P
 
     from ..tl.ring_dma import _accum
-    from ..utils.jaxshim import shard_map_compat
 
     plans = plan_rounds(prog, n, root)
     ce = count // prog.nchunks
@@ -435,7 +435,9 @@ def _build_xla_device_program(mesh, prog: Program, n: int, count: int,
             vec = vec.astype(x.dtype)
         return vec
 
-    program = jax.jit(shard_map_compat(body, mesh, P("r"), P("r")))
+    program = jax.jit(jax.shard_map(body, mesh=mesh,
+                                    in_specs=P("r"),
+                                    out_specs=P("r"), check_vma=False))
     return program, count
 
 
@@ -493,22 +495,18 @@ def _build_pallas_device_program(mesh, prog: Program, n: int, count: int,
     from jax.sharding import PartitionSpec as P
 
     from ..tl.ring_dma import (_accum, _all_rank_barrier, _compiler_params,
-                               _make_step_dma, _neighbor_barrier,
-                               _warn_no_barrier)
-    from ..utils.jaxshim import shard_map_compat
+                               _make_step_dma, _neighbor_barrier, _slots)
 
     plans = plan_rounds(prog, n, root)
     ce = count // prog.nchunks
     accfn = _accum(op) if prog.coll in _REDUCING else None
     ring = ring_schedule(plans, n)
-    interpret = jax.devices()[0].platform == "cpu"
+    interpret = not is_tpu(mesh)
     # collective_id 10: 0-6 are ring_dma's kernel families, 7/8 the
     # fused attention kernels, 9 the HBM alltoall — a shared id would
     # alias the global barrier semaphore across overlapping dispatches
-    cp = _compiler_params(collective_id=10)
-    if cp is None:
-        _warn_no_barrier()
-    barrier = not interpret and cp is not None
+    cp = _compiler_params(10, n)
+    barrier = not interpret
 
     if ring is not None:
         blk = ring[0][0] * ce
@@ -532,7 +530,8 @@ def _build_pallas_device_program(mesh, prog: Program, n: int, count: int,
             o_ref[:] = x_ref[:]
             ack = (ack_sem, left, lambda t: t >= 1,
                    lambda t: t <= n_steps - 2) if barrier else None
-            step_dma = _make_step_dma(comm, send_sem, recv_sem, right,
+            slots = _slots(comm, blk)
+            step_dma = _make_step_dma(slots, send_sem, recv_sem, right,
                                       ack=ack)
             for t in range(n_steps):
                 rs = step_dma(
@@ -540,15 +539,15 @@ def _build_pallas_device_program(mesh, prog: Program, n: int, count: int,
                 roff = tab_ref[2 * t + 1, me]
                 if kinds[t] == OpKind.REDUCE:
                     o_ref[pl.ds(roff, blk)] = accfn(
-                        o_ref[pl.ds(roff, blk)], comm[rs])
+                        o_ref[pl.ds(roff, blk)], slots(rs)[...])
                 else:
-                    o_ref[pl.ds(roff, blk)] = comm[rs]
+                    o_ref[pl.ds(roff, blk)] = slots(rs)[...]
 
         kernel = ring_kernel
 
         def scratch_fn(dtype):
             return [
-                pltpu.VMEM((2, blk), dtype),       # 2-slot comm (parity)
+                pltpu.VMEM((2 * blk,), dtype),     # 2-slot comm (parity)
                 pltpu.SemaphoreType.DMA((2,)),
                 pltpu.SemaphoreType.DMA((2,)),
                 pltpu.SemaphoreType.REGULAR,       # consumption acks
@@ -714,8 +713,7 @@ def _build_pallas_device_program(mesh, prog: Program, n: int, count: int,
             ]
 
     def body(x):
-        kw = {"compiler_params": cp} if cp is not None and not interpret \
-            else {}
+        kw = {} if interpret else {"compiler_params": cp}
         shapes = scratch_fn(x.dtype)
         tabs = [jnp.asarray(tab)]
         specs = [pl.BlockSpec(memory_space=pltpu.SMEM)]
@@ -738,7 +736,9 @@ def _build_pallas_device_program(mesh, prog: Program, n: int, count: int,
                 out.dtype)
         return out
 
-    program = jax.jit(shard_map_compat(body, mesh, P("r"), P("r")))
+    program = jax.jit(jax.shard_map(body, mesh=mesh,
+                                    in_specs=P("r"),
+                                    out_specs=P("r"), check_vma=False))
     return program, count
 
 
@@ -786,8 +786,9 @@ def gen_device_enabled(team) -> bool:
 
 
 def device_backend(team) -> str:
-    """UCC_GEN_DEVICE_BACKEND: auto (pallas on real TPU platforms, xla
-    on the CPU mesh), xla, or pallas (interpret-mode kernels on CPU)."""
+    """UCC_GEN_DEVICE_BACKEND: auto (= xla), xla, or pallas
+    (interpret-mode kernels on the CPU mesh; refused on a TPU mesh,
+    whose compiler rejects the lowering)."""
     from .registry import _cfg_str
     raw = _cfg_str(team, "gen_device_backend",
                    "UCC_GEN_DEVICE_BACKEND", "auto")
@@ -907,17 +908,15 @@ def _make_task_class():
                         f"quantized {qp.mode} predicted error exceeds "
                         f"error budget {qp.budget:.4f}")
             root = int(args.root or 0) if coll == CollType.BCAST else 0
-            try:
-                plat = team.shared.mesh.devices.flat[0].platform
-            except Exception:  # noqa: BLE001 - stub teams
-                plat = "cpu"
-            resolved = backend
+            resolved = "xla" if backend == "auto" else backend
             qblock = qp.block if qp is not None else 0
-            if backend == "auto":
-                resolved = "pallas" if plat != "cpu" and pallas_fits(
-                    program, team.size, total, qblock or 256, root) \
-                    else "xla"
-            elif backend == "pallas":
+            if resolved == "pallas":
+                if is_tpu(team.shared.mesh):
+                    # Mosaic cannot prove the table-driven offsets
+                    # tile-aligned (ROADMAP A4): interpret mode only
+                    raise UccError(Status.ERR_NOT_SUPPORTED,
+                                   "the pallas device lowering does not "
+                                   "compile for TPU")
                 if not pallas_fits(program, team.size, total,
                                    qblock or 256, root):
                     raise UccError(Status.ERR_NOT_SUPPORTED,

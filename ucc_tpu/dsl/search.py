@@ -1218,8 +1218,8 @@ def main(argv: Optional[List[str]] = None) -> int:
     if args.npp:
         os.environ["UCC_TOPO_FAKE_NODES_PER_POD"] = args.npp
     from ..utils.config import parse_memunits
-    from ..utils.jaxshim import ensure_live_backend
-    ensure_live_backend(virtual_cpu_devices=4)
+    from ..utils.backend import setup_backend
+    setup_backend(virtual_cpu_devices=4)
     sizes = [parse_memunits(t) for t in args.sizes.split(",")
              if t.strip()]
     colls = [c.strip() for c in args.colls.split(",") if c.strip()]

@@ -601,11 +601,11 @@ def _run_search_smoke_body(rec: dict, n: int, size: int, budget: int,
 
 def main(argv: Optional[List[str]] = None) -> int:
     argv = sys.argv[1:] if argv is None else argv
-    from ucc_tpu.utils.jaxshim import ensure_live_backend
+    from ucc_tpu.utils.backend import setup_backend
     ndev = 4
     if argv and argv[0] == "--device-bench":
         ndev = max(int(argv[1]) if len(argv) > 1 else 8, 4)
-    ensure_live_backend(virtual_cpu_devices=ndev)
+    setup_backend(virtual_cpu_devices=ndev)
     if argv and argv[0] == "--search":
         try:
             rec = run_search_smoke()

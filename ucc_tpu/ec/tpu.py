@@ -13,8 +13,8 @@ async completion, ec_cuda_executor.c) on TPU terms:
   - completion is device-driven: an executor task completes when its output
     array is ready — the role the CUDA persistent/interruptible kernels play
     for streams (ec_cuda_executor_persistent.c), expressed the XLA way
-  - on non-TPU backends the same kernels run in Pallas interpret mode, so
-    the component is testable on the virtual CPU mesh
+  - sources on a non-TPU device run the same kernels in Pallas interpret
+    mode, so the component is testable on the virtual CPU mesh
 
 jax.Arrays are immutable: tasks deliver results via ``task.array`` and the
 caller rebinds (same convention as TL/XLA dst buffers).
@@ -123,7 +123,9 @@ class EcTpu(Executor):
         super().__init__()
         import jax
         self.jax = jax
-        self.interpret = jax.default_backend() != "tpu"
+        #: whether the last reduce ran its kernel in Pallas interpret
+        #: mode — decided per call from the device its sources live on
+        self.interpret: Optional[bool] = None
 
     # ------------------------------------------------------------------
     def _pad_stack(self, srcs: Sequence[Any], count: int, nd: np.dtype):
@@ -150,6 +152,8 @@ class EcTpu(Executor):
         if op in (ReductionOp.MINLOC, ReductionOp.MAXLOC):
             return self._reduce_loc(srcs, count, dt, op)
         stacked, rows, padded = self._pad_stack(srcs, count, nd)
+        self.interpret = \
+            next(iter(stacked.devices())).platform != "tpu"
         kern = _build_reduce_kernel(len(srcs), rows, nd.name, op,
                                     alpha is not None, self.interpret)
         if alpha is not None:

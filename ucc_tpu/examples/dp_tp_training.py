@@ -27,13 +27,6 @@ from ..constants import ReductionOp
 from .. import ops
 
 
-def _shard_map():
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map
-    from jax.experimental.shard_map import shard_map
-    return shard_map
-
-
 def init_params(d_model: int, d_hidden: int, key=None):
     key = key if key is not None else jax.random.PRNGKey(0)
     k1, k2 = jax.random.split(key)
@@ -48,8 +41,6 @@ def make_train_step(mesh: Mesh, lr: float = 1e-2):
     Shardings: x: P('dp', None); w1: P(None, 'tp') (column-parallel);
     w2: P('tp', None) (row-parallel); outputs replicated.
     """
-    sm = _shard_map()
-
     def step_shard(w1, w2, x, y):
         # forward: column-parallel w1 -> local gelu -> row-parallel w2
         h = jnp.dot(x, w1)                      # (b_local, hid/tp)
@@ -77,13 +68,8 @@ def make_train_step(mesh: Mesh, lr: float = 1e-2):
 
     in_specs = (P(None, "tp"), P("tp", None), P("dp", None), P("dp", None))
     out_specs = (P(None, "tp"), P("tp", None), P(None, None))
-    try:
-        fn = sm(step_shard, mesh=mesh, in_specs=in_specs,
-                out_specs=out_specs, check_vma=False)
-    except TypeError:
-        fn = sm(step_shard, mesh=mesh, in_specs=in_specs,
-                out_specs=out_specs, check_rep=False)
-    return jax.jit(fn)
+    return jax.jit(jax.shard_map(step_shard, mesh=mesh, in_specs=in_specs,
+                                 out_specs=out_specs, check_vma=False))
 
 
 def _gelu_grad(x):

@@ -31,7 +31,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from .. import ops
 from ..constants import ReductionOp
 from ..fused_attention import ring_flash_attention
-from ..utils.jaxshim import shard_map_compat
 
 
 def init_params(heads: int, d: int, key=None):
@@ -60,10 +59,10 @@ def _make_step(mesh: Mesh, make_loss, xspec, pspec, lr: float):
         new = [p - lr * g for p, g in zip((wq, wk, wv, wo), grads)]
         return (loss, *new)
 
-    fn = shard_map_compat(
-        step_shard, mesh,
-        (pspec, pspec, pspec, pspec, xspec, xspec),
-        (P(), pspec, pspec, pspec, pspec))
+    fn = jax.shard_map(step_shard, mesh=mesh,
+                       in_specs=(pspec, pspec, pspec, pspec, xspec, xspec),
+                       out_specs=(P(), pspec, pspec, pspec, pspec),
+                       check_vma=False)
     return jax.jit(fn)
 
 

@@ -19,7 +19,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from .. import ops
-from ..utils.jaxshim import shard_map_compat
 
 
 def make_moe_layer(mesh: Mesh, d_model: int, capacity: int,
@@ -58,8 +57,9 @@ def make_moe_layer(mesh: Mesh, d_model: int, capacity: int,
         y = combined[assign, pos] * keep[:, None].astype(x.dtype)
         return y
 
-    return jax.jit(shard_map_compat(
-        layer, mesh, (P(axis), P(axis), P(axis), P(axis)), P(axis)))
+    return jax.jit(jax.shard_map(layer, mesh=mesh,
+                                 in_specs=(P(axis), P(axis), P(axis), P(axis)),
+                                 out_specs=P(axis), check_vma=False))
 
 
 def reference_moe(x, w_up, w_dn, assign, capacity: int):

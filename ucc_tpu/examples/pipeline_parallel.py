@@ -19,7 +19,6 @@ from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
 from .. import ops
-from ..utils.jaxshim import shard_map_compat
 
 
 def make_pipeline(mesh: Mesh, n_micro: int, axis: str = "pp"):
@@ -70,7 +69,9 @@ def make_pipeline(mesh: Mesh, n_micro: int, axis: str = "pp"):
         # across the pp axis IS the replicated output
         return ops.allreduce(outputs, axis_name=axis)
 
-    fn = shard_map_compat(pipe, mesh, (P(None), P(axis)), P(None))
+    fn = jax.shard_map(pipe, mesh=mesh,
+                       in_specs=(P(None), P(axis)),
+                       out_specs=P(None), check_vma=False)
     return jax.jit(fn)
 
 

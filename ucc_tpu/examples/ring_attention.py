@@ -29,7 +29,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from .. import ops
 
 
-from ..utils.jaxshim import shard_map_compat
 
 
 def _ring_attention_shard(q, k, v, axis_name: str):
@@ -74,7 +73,9 @@ def make_ring_attention(mesh: Mesh, axis_name: str = "sp"):
     """
     spec = P(None, axis_name, None)
     fn = functools.partial(_ring_attention_shard, axis_name=axis_name)
-    return jax.jit(shard_map_compat(fn, mesh, (spec, spec, spec), spec))
+    return jax.jit(jax.shard_map(fn, mesh=mesh,
+                                 in_specs=(spec, spec, spec),
+                                 out_specs=spec, check_vma=False))
 
 
 def _ulysses_shard(q, k, v, axis_name: str):
@@ -114,7 +115,9 @@ def _ulysses_shard(q, k, v, axis_name: str):
 def make_ulysses_attention(mesh: Mesh, axis_name: str = "sp"):
     spec = P(None, axis_name, None)
     fn = functools.partial(_ulysses_shard, axis_name=axis_name)
-    return jax.jit(shard_map_compat(fn, mesh, (spec, spec, spec), spec))
+    return jax.jit(jax.shard_map(fn, mesh=mesh,
+                                 in_specs=(spec, spec, spec),
+                                 out_specs=spec, check_vma=False))
 
 
 def reference_attention(q, k, v):

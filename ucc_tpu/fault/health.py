@@ -34,7 +34,7 @@ contains a failed rank with ``Status.ERR_RANK_FAILED`` (stamping
 ``task.failed_ranks`` for attribution), bumps the
 ``rank_failures_detected`` metric, and — when the watchdog is armed —
 appends a ``rank_failed`` evidence line to the watchdog file so
-``tools/tpu_probe.py`` / ``tools/snapshot_gate.py`` classify the run
+``tools/snapshot_gate.py`` classifies the run
 ``rank_failed(ranks=...)`` instead of ``hang``.
 """
 from __future__ import annotations

@@ -21,9 +21,9 @@ max/normalizer in f32, so the result equals full softmax(QK^T)V over the
 entire (sequence-sharded) context. Optional causal masking uses global
 positions (rank r owns queries/keys [r*S_local, (r+1)*S_local)).
 
-Compiled on real TPU meshes; Pallas interpret mode on the virtual CPU
-mesh (tests). Same hardware gate as ring_dma: the compiled ICI path
-needs real-chip validation.
+Compiled (Mosaic) on TPU meshes; Pallas interpret mode on the virtual
+CPU mesh (tests). tests/test_tpu_compile.py compiles it for a described
+v5e:2x2 at H=2, S_local=128, D=128; it has not run on a chip.
 
 VMEM budget: per chip the kernel holds the q/o blocks (H heads), the
 f32 accumulators (H·S_local rows folded as h_kv·g·S_local), and the k/v
@@ -32,13 +32,17 @@ inputs plus 2x2 double-buffer K/V slots at h_kv heads only — roughly
 2·bytes32/bytes_in·H·S_local·D + 4·H·S_local`` elements, i.e. for MHA
 (h_kv = H): ``(4 + 3·bytes32/bytes_in)·H·S_local·D + 4·H·S_local``;
 under GQA the K/V-slot term shrinks by H/h_kv. Size S_local so this
-stays under ~16 MiB/core.
+stays under the 16 MiB scoped-VMEM limit: at H=8, S_local=512, D=128
+bf16 Mosaic refuses it (21 MiB), and H=8, S_local=2048 did not finish
+compiling in 400 s (PR 21).
 """
 from __future__ import annotations
 
 import functools
 
 import numpy as np
+
+from .utils.backend import is_tpu
 
 
 def _kernel(n: int, scale: float, causal: bool, s_local: int,
@@ -158,24 +162,21 @@ def _kernel(n: int, scale: float, causal: bool, s_local: int,
 @functools.lru_cache(maxsize=64)
 def _build(n: int, h: int, s_local: int, d: int, dtype_str: str,
            scale: float, causal: bool, axis: str, h_kv: int,
-           multi_axis: bool = False):
+           multi_axis: bool, interpret: bool):
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    from .tl.ring_dma import _compiler_params, _warn_no_barrier
+    from .tl.ring_dma import _compiler_params
 
-    interpret = jax.devices()[0].platform == "cpu"
-    cp = _compiler_params(collective_id=8 if multi_axis else 7)
-    if cp is None:
-        _warn_no_barrier()
+    cp = _compiler_params(8 if multi_axis else 7, n)
     nd = jnp.dtype(dtype_str)
     g = h // h_kv
     kernel = _kernel(n, scale, causal, s_local, axis,
-                     barrier=not interpret and cp is not None,
+                     barrier=not interpret,
                      h_kv=h_kv, g=g, multi_axis=multi_axis)
-    kw = {"compiler_params": cp} if cp is not None and not interpret else {}
+    kw = {} if interpret else {"compiler_params": cp}
 
     def shard_fn(q, k, v):
         return pl.pallas_call(
@@ -322,15 +323,15 @@ def ring_flash_attention(q, k, v, *, axis_name: str = "r",
     # addressing mode — LOGICAL vs dict MESH device ids — must not ride
     # on the trace-time probe when the mesh shape is in hand)
     multi = _mesh_multi_axis() if multi_axis is None else bool(multi_axis)
+    interpret = not is_tpu()
     if fused is None:
-        interpret = jax.devices()[0].platform == "cpu"
         fused = not (multi and interpret)
     if not fused:
         return _xla_ring_shard(q, k, v, int(n), float(scale),
                                bool(causal), axis_name)
     fused = _build(int(n), h, s_local, d, str(q.dtype), float(scale),
                    bool(causal), axis_name, multi_axis=multi,
-                   h_kv=h_kv)
+                   h_kv=h_kv, interpret=interpret)
 
     @jax.custom_vjp
     def attn(q, k, v):
@@ -362,18 +363,18 @@ def make_ring_flash_attention(mesh, *, causal: bool = False,
     import jax
     from jax.sharding import PartitionSpec as P
 
-    from .utils.jaxshim import shard_map_compat
-
     def body(q, k, v):
         # the mesh is known here: choose the path explicitly instead of
         # relying on the trace-time probe. Fused everywhere except
         # interpret (CPU) on a multi-axis mesh — the one shape the
         # interpret discharge rule cannot run.
         multi = len(mesh.axis_names) > 1
-        fused = not multi or mesh.devices.flat[0].platform != "cpu"
+        fused = not multi or is_tpu(mesh)
         return ring_flash_attention(q, k, v, axis_name=axis, scale=scale,
                                     causal=causal, fused=fused,
                                     multi_axis=multi)
 
-    return jax.jit(shard_map_compat(
-        body, mesh, (P(None, axis, None),) * 3, P(None, axis, None)))
+    return jax.jit(jax.shard_map(body, mesh=mesh,
+                                 in_specs=(P(None, axis, None),) * 3,
+                                 out_specs=P(None, axis, None),
+                                 check_vma=False))

@@ -1,13 +1,13 @@
 """Stall watchdog — turns silent hangs into actionable state dumps.
 
-Motivation: round 5 ended with the chip wedged for the whole round and
-`TPU_PROBE_r05.log` all ``hang`` — no way to tell WHICH collective,
-task, or peer was stuck. This module hooks the progress queue
-(schedule/progress.py): any task IN_PROGRESS past a soft deadline
-(``UCC_WATCHDOG_TIMEOUT`` seconds; unset/0 = off, the default) fires a
-ONE-SHOT diagnostic dump — every in-flight task with its collective,
-algorithm, round/slots, outstanding peers and tags, the progress-queue
-depth, and every live team's state-machine position (CL_AGREE dwell is
+Motivation: a run that only records ``hang`` gives no way to tell
+WHICH collective, task, or peer was stuck. This module hooks the
+progress queue (schedule/progress.py): any task IN_PROGRESS past a soft
+deadline (``UCC_WATCHDOG_TIMEOUT`` seconds; unset/0 = off, the default)
+fires a ONE-SHOT diagnostic dump — every in-flight task with its
+collective, algorithm, round/slots, outstanding peers and tags, the
+progress-queue depth, and every live team's state-machine position
+(CL_AGREE dwell is
 named explicitly: the advisor-confirmed silent-hang path in
 core/team.py) — to the log at ERROR and as a JSON line appended to
 ``UCC_WATCHDOG_FILE``.
@@ -101,9 +101,9 @@ def register_team(team: Any) -> None:
 def note_rank_failure(ranks, source: str = "", detail: str = "") -> None:
     """Append a ``rank_failed`` evidence line to the watchdog file
     (called by fault/health on detection). Only when the watchdog is
-    armed — CI harnesses (tools/tpu_probe.py, tools/snapshot_gate.py)
-    always arm it, and parse this line to classify a run
-    ``rank_failed(ranks=...)`` instead of ``hang``/``timeout``."""
+    armed — tools/snapshot_gate.py always arms it, and parses this line
+    to classify a run ``rank_failed(ranks=...)`` instead of
+    ``hang``/``timeout``."""
     if not ENABLED:
         return
     rec = {"ts": time.time(), "pid": os.getpid(), "reason": "rank_failed",

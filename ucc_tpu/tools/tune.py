@@ -312,8 +312,8 @@ def main(argv=None) -> int:
         if args.gen != "all":
             os.environ["UCC_GEN_FAMILIES"] = args.gen
 
-    from ucc_tpu.utils.jaxshim import ensure_live_backend
-    ensure_live_backend(virtual_cpu_devices=max(args.nprocs, 4))
+    from ucc_tpu.utils.backend import setup_backend
+    setup_backend(virtual_cpu_devices=max(args.nprocs, 4))
 
     if args.gate_smoke:
         return run_gate_smoke(args.iters if args.iters != 20 else 10)
