@@ -144,12 +144,12 @@ class TestOnline:
             srcs = [np.ones(COUNT, np.float32) for _ in range(4)]
             dsts = [np.zeros(COUNT, np.float32) for _ in range(4)]
             reqs = _persistent_allreduce(teams, srcs, dsts)
-            # probe lane bound while exploring: post is an instance attr
-            assert all("post" in rq.__dict__ for rq in reqs)
+            # probe lane bound while exploring: _post is an instance attr
+            assert all("_post" in rq.__dict__ for rq in reqs)
             _drive(job, reqs, FREEZE_ROUNDS + 1, dsts, 4)
             # converged: exploration bounded by the sample budget, then
             # the deterministic hold window, then frozen + unbound
-            assert all("post" not in rq.__dict__ for rq in reqs)
+            assert all("_post" not in rq.__dict__ for rq in reqs)
             assert all(not t.tuner.exploring(
                 t.tuner.key_for(CollType.ALLREDUCE, MemoryType.HOST,
                                 NBYTES)) for t in teams)
@@ -207,7 +207,7 @@ class TestOnline:
             srcs = [np.ones(COUNT, np.float32) for _ in range(4)]
             dsts = [np.zeros(COUNT, np.float32) for _ in range(4)]
             reqs = _persistent_allreduce(teams2, srcs, dsts)
-            assert all("post" not in rq.__dict__ for rq in reqs)
+            assert all("_post" not in rq.__dict__ for rq in reqs)
             assert all(rq.task.alg_name == winner for rq in reqs)
             _drive(job2, reqs, 2, dsts, 4)
             assert all(not t.tuner._keys for t in teams2)  # zero explored
@@ -233,7 +233,7 @@ class TestOnline:
             d2 = [np.zeros(COUNT, np.float32) for _ in range(2)]
             r1 = _persistent_allreduce(teams, srcs, d1)
             r2 = _persistent_allreduce(teams, srcs, d2)
-            assert all("post" in rq.__dict__ for rq in r1 + r2)
+            assert all("_post" in rq.__dict__ for rq in r1 + r2)
             # overlap: post BOTH requests on every rank before waiting
             for rq in r1:
                 rq.post()
@@ -260,7 +260,7 @@ class TestOnline:
                     rq.post()
                 job.progress_until(lambda: all(
                     rq.test() != Status.IN_PROGRESS for rq in r1))
-            assert all("post" not in rq.__dict__ for rq in r1 + r2)
+            assert all("_post" not in rq.__dict__ for rq in r1 + r2)
             assert len({rq.task.alg_name for rq in r1}) == 1
             for rq in r1 + r2:
                 rq.finalize()
@@ -299,7 +299,7 @@ class TestOffModes:
             reqs = _persistent_allreduce(teams, srcs, dsts)
             # no probe lane: post stays the plain class method (the
             # UCC_TUNER=off byte-identical dispatch contract)
-            assert all("post" not in rq.__dict__ for rq in reqs)
+            assert all("_post" not in rq.__dict__ for rq in reqs)
             assert all(rq._tuner is None for rq in reqs)
             _drive(job, reqs, 2, dsts, 2)
             for rq in reqs:

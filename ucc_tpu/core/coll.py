@@ -99,7 +99,7 @@ class CollRequest:
 
     #: autotuner probe lane (score/tuner.py): while a (coll, mem,
     #: size-bucket) key is still exploring, ``_bind_tuner`` shadows the
-    #: class ``post`` with ``_tuner_post`` as an INSTANCE attribute —
+    #: class ``_post`` with ``_tuner_post`` as an INSTANCE attribute —
     #: the PR-3 ``_instr`` binding pattern, so UCC_TUNER=off adds no
     #: per-post branch to this hot path
     _tuner = None
@@ -170,7 +170,18 @@ class CollRequest:
         return None
 
     def post(self) -> Status:
-        """ucc_collective_post (ucc_coll.c:375)."""
+        """ucc_collective_post (ucc_coll.c:375), inside the ``ucc.post``
+        layer span on every lane (plain, fast re-post, tuner, coalesce)."""
+        tok = profiling.begin("ucc.post")
+        if tok is None:
+            return self._post()
+        try:
+            return self._post()
+        finally:
+            tok.set_metadata(seq=self.task.seq_num)
+            profiling.end(tok)
+
+    def _post(self) -> Status:
         st = self.task.super_status
         if self._posted:
             if st == Status.IN_PROGRESS:
@@ -252,7 +263,7 @@ class CollRequest:
         self._tuner_cur = chosen
         self._tuner_user_cb = self.task.cb   # restore target on unbind
         self._tuner_wrapped_cb = None
-        self.post = self._tuner_post         # shadow the class method
+        self._post = self._tuner_post        # shadow the class method
 
     def _tuner_unbind(self) -> None:
         if self._tuner_wrapped_cb is not None and \
@@ -260,7 +271,7 @@ class CollRequest:
             self.task.cb = self._tuner_user_cb
         self._tuner_wrapped_cb = None
         self._tuner = None
-        self.__dict__.pop("post", None)      # back to the class post
+        self.__dict__.pop("_post", None)     # back to the class post
 
     def _tuner_swap_task(self, cand, new_task) -> None:
         old = self.task
@@ -318,7 +329,7 @@ class CollRequest:
             # across ranks, so leaving without consuming a rotation
             # slot cannot desynchronize the counters)
             self._tuner_unbind()
-            return self.post()
+            return self._post()
         tuner = self._tuner
         key = self._tuner_key
         frozen, winner = tuner.poll(key)
@@ -326,13 +337,13 @@ class CollRequest:
             if winner is not None:
                 self._tuner_swap_to_winner(winner)
             self._tuner_unbind()
-            return self.post()
+            return self._post()
         if not tuner.claim(key, self):
             # another un-finalized request drives this key (overlapped
             # posts): the key just froze to static defaults — leave the
             # probe lane without consuming a rotation slot
             self._tuner_unbind()
-            return self.post()
+            return self._post()
         new_task = None
         chosen = None
         for cand in tuner.explore_order(key, self._tuner_cands):
@@ -356,7 +367,7 @@ class CollRequest:
         if new_task is None:
             # nothing explorable survived init: leave the probe lane
             self._tuner_unbind()
-            return self.post()
+            return self._post()
         if new_task is not task:
             self._tuner_swap_task(chosen, new_task)
         elif self._posted:
@@ -520,7 +531,25 @@ def _is_zero_size(args: CollArgs) -> bool:
 
 
 def collective_init(args: CollArgs, team: Team) -> CollRequest:
-    """ucc_collective_init (ucc_coll.c:172)."""
+    """ucc_collective_init (ucc_coll.c:172), inside the ``ucc.init``
+    layer span (its ``ucc.select`` and ``ucc.tl_init`` children split
+    it)."""
+    tok = profiling.begin("ucc.init")
+    if tok is None:
+        return _init_request(args, team)
+    req = None
+    try:
+        req = _init_request(args, team)
+        return req
+    finally:
+        if req is not None:
+            t = req.task
+            tok.set_metadata(seq=t.seq_num, coll=t.coll_name or "",
+                             alg=t.alg_name or "")
+        profiling.end(tok)
+
+
+def _init_request(args: CollArgs, team: Team) -> CollRequest:
     if team._shrunk:
         # the old epoch's tag space is fenced; collectives must move to
         # the successor team the Shrink/Grow request returned
@@ -572,6 +601,7 @@ def collective_init(args: CollArgs, team: Team) -> CollRequest:
     init_args = InitArgs(args=args, team=team, mem_type=mem_type,
                          msgsize=msgsize)
     assert team.score_map is not None
+    tok = profiling.begin("ucc.select")
     bias = team.rank_bias
     if bias is not None:
         # promote any staged straggler-feedback table at its
@@ -581,8 +611,15 @@ def collective_init(args: CollArgs, team: Team) -> CollRequest:
         # same post everywhere — the tuner-switch divergence argument
         bias.tick(team.flight_seq)
     candidates = team.score_map.lookup(ct, mem_type, msgsize, bias=bias)
-    task, chosen = team.score_map.init_coll(ct, mem_type, msgsize, init_args,
-                                            candidates)
+    if tok is not None:
+        profiling.end(tok)
+    tok = profiling.begin("ucc.tl_init")
+    try:
+        task, chosen = team.score_map.init_coll(ct, mem_type, msgsize,
+                                                init_args, candidates)
+    finally:
+        if tok is not None:
+            profiling.end(tok)
     # observability labels: metrics key the (collective, algorithm) pair
     # and the watchdog dump names both; stamped once at init, read only
     # on cold paths

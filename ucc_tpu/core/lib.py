@@ -31,7 +31,6 @@ GLOBAL_CONFIG = register_table(ConfigTable(prefix="", name="global", fields=[
                 "with the selected CL/TL", parse_bool),
     ConfigField("PROFILE_MODE", "", "profiling mode: log,accum", parse_string),
     ConfigField("PROFILE_FILE", "", "profiling output file", parse_string),
-    ConfigField("PROFILE_LOG_SIZE", "4m", "profiling buffer size", parse_string),
     # the obs knobs are read from the environment at import by
     # ucc_tpu/obs (same zero-cost pattern as PROFILE_MODE above); listed
     # here so `ucc_info -cf` documents them

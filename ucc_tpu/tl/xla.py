@@ -34,11 +34,12 @@ import numpy as np
 
 from ..api.types import BufferInfo, BufferInfoV
 from ..constants import (COLL_TYPE_ALL, CollType, MemoryType, ReductionOp,
-                         dt_numpy)
+                         coll_type_str, dt_numpy)
 from ..core.components import BaseContext, BaseLib, TransportLayer, register_tl
 from ..schedule.task import CollTask
 from ..score.score import CollScore
 from ..status import Status, UccError
+from ..utils import profiling
 from ..utils.config import (ConfigField, ConfigTable, parse_string,
                             register_table)
 from ..utils.ep_map import EpMap
@@ -197,7 +198,11 @@ class XlaTeamShared:
             if ready:
                 del self.pending[tag]
         if ready:
+            tok = profiling.begin("ucc.xla.launch")
             self._launch(slot)
+            if tok is not None:
+                tok.set_metadata(tag=tag)
+                profiling.end(tok)
 
     def _launch(self, slot) -> None:
         import jax
@@ -238,7 +243,10 @@ class XlaTeamShared:
                 # would evict exactly the entries doing the work)
                 self.launch_cache[proto.tag] = \
                     self.launch_cache.pop(proto.tag)
+                tok = profiling.begin("ucc.xla.dispatch")
                 out = program(garr)
+                if tok is not None:
+                    profiling.end(tok)
                 if perm is None:
                     by_dev = {s.device: s.data
                               for s in out.addressable_shards}
@@ -258,13 +266,19 @@ class XlaTeamShared:
             global_shape = (n * count_padded,)
             from jax.sharding import NamedSharding, PartitionSpec as P
             sharding = NamedSharding(self.mesh, P("r"))
+            tok = profiling.begin("ucc.xla.stage")
             shards = []
             for rank, (buf, task) in sorted(slot.items()):
                 row = task.shard_for_launch(buf, count_padded)
                 shards.append(jax.device_put(row, self.devices[rank]))
             garr = jax.make_array_from_single_device_arrays(
                 global_shape, sharding, shards)
+            if tok is not None:
+                profiling.end(tok)
+            tok = profiling.begin("ucc.xla.dispatch")
             out = program(garr)
+            if tok is not None:
+                profiling.end(tok)
             if proto.args.is_persistent:
                 # AOT-compile for re-posts: the Compiled object's dispatch
                 # skips jit's python-side signature matching (~100us/call).
@@ -398,6 +412,7 @@ class XlaTeamShared:
                 def body(x):
                     return ops.reduce_scatter(x[None, :], op)[0]
 
+                body.__name__ = f"ucc_reduce_{proto.alg}"
                 program = jax.jit(jax.shard_map(body, mesh=self.mesh,
                                                 in_specs=P("r"),
                                                 out_specs=P("r"),
@@ -878,6 +893,7 @@ class XlaCollTask(CollTask):
         def body(x):                 # (max_src,) raw flat send buffer
             return a2av_exchange(x, pidx_c, uidx_c, n, maxblk, max_src)
 
+        body.__name__ = f"ucc_alltoallv_{self.alg}"
         program = jax.jit(jax.shard_map(body, mesh=shared.mesh,
                                         in_specs=P("r"),
                                         out_specs=P("r"), check_vma=False))
@@ -1238,6 +1254,9 @@ def _build_xla_program(mesh, n: int, coll: CollType, args, nd, count: int,
 
     def body(x):          # x: (padded,) flat shard; 2-D view inside jit
         return body_2d(x[None, :])[0]
+
+    # names the program in profiles (``jit_ucc_allreduce_xla``)
+    body.__name__ = f"ucc_{coll_type_str(coll)}_{alg}"
 
     in_specs = P("r")
     if coll in (CollType.ALLGATHER, CollType.GATHER, CollType.ALLGATHERV,

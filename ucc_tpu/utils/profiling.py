@@ -10,6 +10,14 @@ TPU build: JSON-lines trace (chrome://tracing-compatible events) written to
 module-level boolean checked before any formatting — the Python analog of
 the compiled-out macros. ``accum`` mode aggregates per-(event,coll) counts
 and total times, dumped at exit.
+
+Layer spans (``begin``/``end``) time the boundaries of the post path —
+init, selection, TL task creation, post, the TL/XLA launch — and are on
+whenever a JAX profiler session is capturing or ``UCC_PROFILE_MODE`` is
+set. Each one opens a ``TraceMe("ucc.<layer>")`` on the calling thread, so
+it lands in the captured profile on the device trace's clock, nested in
+whatever span the caller holds, and adds its duration to an in-memory
+table that ``totals()`` reads. Off, ``begin`` is one gate check.
 """
 from __future__ import annotations
 
@@ -18,7 +26,9 @@ import json
 import os
 import threading
 import time
-from typing import Any, Dict, Optional
+from typing import Any, Dict, List, Optional, Tuple
+
+from jax.profiler import TraceAnnotation as _TraceMe
 
 _mode = os.environ.get("UCC_PROFILE_MODE", "").strip().lower()
 ENABLED = _mode in ("log", "accum")
@@ -27,6 +37,13 @@ _lock = threading.Lock()
 _fh = None
 _accum: Dict[str, Dict[str, float]] = {}
 _t0 = time.perf_counter()
+#: layer-span gate: True while a profiler session captures (one native
+#: call), or always under UCC_PROFILE_MODE
+_spans_on = (lambda: True) if ENABLED else _TraceMe.is_enabled
+#: layer-span tables, one per thread that closed a span (each written
+#: only by its thread): name -> [count, seconds]
+_tls = threading.local()
+_tables: List[Dict[str, list]] = []
 
 
 def _ensure_fh():
@@ -99,13 +116,76 @@ def span_end(name: str, span: int, **fields: Any) -> None:
     event(name, "E", span=span, **fields)
 
 
+# ---------------------------------------------------------------------------
+# layer spans — scoped, on the profiler's clock, summed in memory
+# ---------------------------------------------------------------------------
+
+class _Span(_TraceMe):
+    """An open layer span: the profiler event itself, plus its name and
+    host start time for the totals table."""
+    __slots__ = ("name", "t0")
+
+
+def begin(name: str) -> Optional[_Span]:
+    """Open layer span ``name`` (``ucc.<layer>``); returns the token
+    ``end`` takes, or None when spans are off (nothing else is done
+    then). ``token.set_metadata(seq=...)`` adds stats to the event."""
+    if not _spans_on():
+        return None
+    sp = _Span(name)
+    sp.name = name
+    sp.__enter__()
+    sp.t0 = time.perf_counter()
+    return sp
+
+
+def end(sp: _Span) -> None:
+    """Close a span ``begin`` opened (callers skip a None token)."""
+    dt = time.perf_counter() - sp.t0
+    sp.__exit__(None, None, None)
+    try:
+        table = _tls.table
+    except AttributeError:
+        table = _tls.table = {}
+        with _lock:
+            _tables.append(table)
+    slot = table.get(sp.name)
+    if slot is None:
+        table[sp.name] = [1, dt]
+    else:
+        slot[0] += 1
+        slot[1] += dt
+
+
+def totals() -> Dict[str, Tuple[int, float]]:
+    """Layer spans closed since the last ``reset``, over all threads:
+    name -> (count, seconds)."""
+    out: Dict[str, Tuple[int, float]] = {}
+    with _lock:
+        tables = [dict(t) for t in _tables]
+    for t in tables:
+        for name, (c, secs) in t.items():
+            c0, s0 = out.get(name, (0, 0.0))
+            out[name] = (c0 + c, s0 + secs)
+    return out
+
+
+def reset() -> None:
+    with _lock:
+        for t in _tables:
+            t.clear()
+
+
 @atexit.register
 def _dump_accum() -> None:
-    if ENABLED and _mode == "accum" and _accum:
+    spans = totals()
+    if ENABLED and _mode == "accum" and (_accum or spans):
+        rows = [(name, slot["count"], slot["total_us"])
+                for name, slot in _accum.items()]
+        rows += [(name, c, secs * 1e6) for name, (c, secs) in spans.items()]
         with open(_file, "a") as fh:
-            for name, slot in sorted(_accum.items()):
+            for name, count, total_us in sorted(rows):
                 fh.write(json.dumps({
-                    "name": name, "count": int(slot["count"]),
-                    "total_us": round(slot["total_us"], 1),
-                    "avg_us": round(slot["total_us"] /
-                                    max(1, slot["count"]), 2)}) + "\n")
+                    "name": name, "count": int(count),
+                    "total_us": round(total_us, 1),
+                    "avg_us": round(total_us / max(1, count), 2)}) + "\n")
