@@ -130,6 +130,88 @@ class TestXlaAllreduce:
             job.cleanup()
 
 
+@pytest.fixture(scope="class")
+def program_job():
+    """A 4-rank job with the short-message path off, so that every
+    allreduce runs the compiled ``xla`` program."""
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("UCC_TL_XLA_SHORT_MSG_MAX", "0")
+        j = UccJob(4)
+        try:
+            yield j, j.create_team()
+        finally:
+            j.cleanup()
+
+
+def _np_allreduce(op, srcs):
+    s = np.stack(srcs).astype(np.float64)
+    if op == ReductionOp.SUM:
+        return s.sum(0)
+    if op == ReductionOp.AVG:
+        return s.sum(0) / len(srcs)
+    if op == ReductionOp.MAX:
+        return s.max(0)
+    if op == ReductionOp.MIN:
+        return s.min(0)
+    if op == ReductionOp.PROD:
+        return s.prod(0)
+    vals, idxs = s[:, 0::2], s[:, 1::2]              # MINLOC pairs
+    low = vals.min(0)
+    out = np.empty(s.shape[1])
+    out[0::2] = low
+    out[1::2] = np.where(vals == low, idxs, np.inf).min(0)
+    return out
+
+
+class TestXlaAllreduceProgram:
+    """The compiled allreduce program on the flat shard, at a count that
+    is no multiple of 128, in 16- and 32-bit floats, for the natively
+    reduced ops and for those reduced through the gather."""
+
+    @pytest.mark.parametrize("inplace", [False, True])
+    @pytest.mark.parametrize("dt,np_dt", [
+        (DataType.BFLOAT16, jnp.bfloat16), (DataType.FLOAT32, np.float32)])
+    @pytest.mark.parametrize("op", [
+        ReductionOp.SUM, ReductionOp.AVG, ReductionOp.MAX, ReductionOp.MIN,
+        ReductionOp.PROD, ReductionOp.MINLOC])
+    def test_matches_numpy(self, program_job, op, dt, np_dt, inplace):
+        job, teams = program_job
+        n, count = 4, 1003
+        rng = np.random.default_rng(int(op) * 4 + int(inplace))
+        if op == ReductionOp.MINLOC:
+            count *= 2                               # (value, index) pairs
+            srcs = [np.empty(count) for _ in range(n)]
+            for r in range(n):
+                srcs[r][0::2] = rng.integers(-4, 4, count // 2)
+                srcs[r][1::2] = r
+        elif op == ReductionOp.PROD:
+            srcs = [rng.integers(1, 4, count) for _ in range(n)]
+        else:
+            srcs = [rng.integers(-64, 64, count) for _ in range(n)]
+        srcs = [s.astype(np_dt) for s in srcs]       # small ints: exact
+        argses = []
+        for r in range(n):
+            buf = tpu_buf(job, r, srcs[r], dt)
+            if inplace:
+                argses.append(CollArgs(coll_type=CollType.ALLREDUCE, dst=buf,
+                                       op=op, flags=CollArgsFlags.IN_PLACE))
+            else:
+                argses.append(CollArgs(
+                    coll_type=CollType.ALLREDUCE, src=buf,
+                    dst=BufferInfo(None, count, dt, mem_type=MemoryType.TPU),
+                    op=op))
+        run_xla(job, teams, lambda r: argses[r])
+        xla_team = next(t for t in teams[0].cl_teams[0].tl_teams
+                        if t.name == "xla")
+        assert any(k[0] == CollType.ALLREDUCE and k[3] == count and
+                   k[4] == "xla" for k in xla_team.shared.programs)
+        expect = _np_allreduce(op, srcs)
+        for r in range(n):
+            out = np.asarray(argses[r].dst.buffer)
+            assert out.shape == (count,)
+            np.testing.assert_array_equal(out.astype(np.float64), expect)
+
+
 class TestXlaOtherColls:
     def test_allgather(self, job, teams):
         n, per = 4, 5

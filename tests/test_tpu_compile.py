@@ -100,6 +100,36 @@ class TestTlXla:
                                  sharding=NamedSharding(mesh, P("r")))
         assert hlo in _compile(prog, x).as_text()
 
+    @pytest.mark.parametrize("count", [11_547_648, 113_246_208])
+    def test_allreduce_flat(self, topo, count):
+        """A bf16 AVG allreduce at the size of the first and the last
+        gradient bucket of Ouro-2.6B's stage 0 runs on the flat shard:
+        the all-reduce reads the entry parameter in its own 1-D layout,
+        with no relayout loop, no zero-filled buffer and no temp."""
+        from ucc_tpu import BufferInfo, CollArgs
+        from ucc_tpu.tl.xla import _build_xla_program
+        n = 4
+        mesh = _mesh(topo, n)
+        args = CollArgs(coll_type=CollType.ALLREDUCE,
+                        src=BufferInfo(None, count, DataType.BFLOAT16),
+                        dst=BufferInfo(None, count, DataType.BFLOAT16),
+                        op=ReductionOp.AVG)
+        prog, padded = _build_xla_program(
+            mesh, n, CollType.ALLREDUCE, args, np.dtype(jnp.bfloat16), count,
+            "xla")
+        x = jax.ShapeDtypeStruct((n * padded,), jnp.bfloat16,
+                                 sharding=NamedSharding(mesh, P("r")))
+        compiled = _compile(prog, x)
+        text = compiled.as_text()
+        entry = text[text.index("\nENTRY"):].splitlines()
+        assert "while(" not in text
+        param = next(ln.split("=")[0].strip() for ln in entry
+                     if " parameter(0)" in ln)
+        assert any(f"all-reduce({param})" in ln for ln in entry)
+        assert not any(" broadcast(" in ln and str(count) in ln
+                       for ln in entry)
+        assert compiled.memory_analysis().temp_size_in_bytes == 0
+
 
 _FAMILIES = ["ring_allreduce", "ring_allgather", "ring_reduce_scatter",
              "bcast", "alltoall", "hbm_allreduce", "hbm_allgather",

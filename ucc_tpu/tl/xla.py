@@ -1252,8 +1252,14 @@ def _build_xla_program(mesh, n: int, coll: CollType, args, nd, count: int,
         raise UccError(Status.ERR_NOT_SUPPORTED,
                        f"tl/xla does not build {coll}")
 
-    def body(x):          # x: (padded,) flat shard; 2-D view inside jit
-        return body_2d(x[None, :])[0]
+    def body(x):          # x: (padded,) flat shard
+        if coll == CollType.ALLREDUCE and alg == "xla":
+            # on the flat shard as it arrives: a [1, N] view of a 16-bit
+            # dtype tiles two rows per tile, one of them padding, and costs
+            # a relayout on the way in, another on the way out, and an
+            # all-reduce of twice the bytes
+            return ops.allreduce(x, op)
+        return body_2d(x[None, :])[0]      # 2-D view inside jit
 
     # names the program in profiles (``jit_ucc_allreduce_xla``)
     body.__name__ = f"ucc_{coll_type_str(coll)}_{alg}"
