@@ -236,3 +236,38 @@ def test_allreduce_program_is_named():
     text = program.lower(
         jax.ShapeDtypeStruct((4 * padded,), jnp.float32)).as_text()
     assert "@jit_ucc_allreduce_xla" in text
+
+
+def test_alltoallv_plan_and_build_nest_in_the_launch(job4, tmp_path):
+    """TL/XLA's alltoallv launch holds ``ucc.xla.a2av.plan`` every time
+    and ``ucc.xla.build`` on a program-cache miss only: a second routing
+    at the same buffer shapes builds nothing."""
+    from ucc_tpu import BufferInfoV
+    job, teams = job4
+    n = len(teams)
+    w, cap = 8, 53                       # rows of 8, 53 rows per source
+
+    def post(m):
+        argses = [CollArgs(
+            coll_type=CollType.ALLTOALLV,
+            src=BufferInfoV(_dev(job, r, np.arange(w * cap,
+                                                   dtype=np.float32)),
+                            [w * int(c) for c in m[r]], None,
+                            DataType.FLOAT32, mem_type=MemoryType.TPU),
+            dst=BufferInfoV(_dev(job, r, np.zeros(n * w * cap, np.float32)),
+                            [w * int(m[p][r]) for p in range(n)], None,
+                            DataType.FLOAT32, mem_type=MemoryType.TPU))
+            for r in range(n)]
+        for rq in job.run_coll(teams, lambda r: argses[r]):
+            rq.finalize()
+
+    rng = np.random.default_rng(0)
+    with _Capture(tmp_path) as cap_:
+        for _ in range(2):
+            post(rng.integers(0, cap // n + 1, size=(n, n)))
+    tot = profiling.totals()
+    assert tot["ucc.xla.launch"][0] == tot["ucc.xla.a2av.plan"][0] == 2
+    assert tot["ucc.xla.build"][0] == 1
+    ev = _host_events(cap_.path)
+    for child in ev["ucc.xla.a2av.plan"] + ev["ucc.xla.build"]:
+        assert _inside(child, ev["ucc.xla.launch"])

@@ -131,6 +131,41 @@ class TestTlXla:
         assert compiled.memory_analysis().temp_size_in_bytes == 0
 
 
+class TestAlltoallv:
+    """TL/XLA's alltoallv at the ep4 cell's shapes (DeepSeek-V2-Lite,
+    EP=4): 24 576 source rows of 2048 bf16 per chip into a 98 304-row
+    destination, the counts as a runtime int32 input."""
+
+    @pytest.mark.parametrize("given", [True, False])
+    def test_ragged_body(self, topo, given):
+        """One ragged-all-to-all, chosen by platform (no portable
+        all-to-all, no conditional); the flat source reaches it through
+        bitcasts, with no relayout into rows; the temp is the one
+        destination-sized buffer of the result."""
+        from ucc_tpu.tl.xla import A2avPlan, _build_a2av_program
+        n, w = 4, 2048
+        plan = A2avPlan(None, w, 24576 * w, 98304 * w, given)
+        mesh = _mesh(topo, n)
+        sh = NamedSharding(mesh, P("r"))
+        shapes = [jax.ShapeDtypeStruct((n * plan.src_cap,), jnp.bfloat16,
+                                       sharding=sh)]
+        if given:
+            shapes.append(jax.ShapeDtypeStruct((n * plan.dst_cap,),
+                                               jnp.bfloat16, sharding=sh))
+        shapes.append(jax.ShapeDtypeStruct((n * 4 * n,), jnp.int32,
+                                           sharding=sh))
+        compiled = _compile(_build_a2av_program(mesh, n, plan, "xla"),
+                            *shapes)
+        text = compiled.as_text()
+        entry = text[text.index("\nENTRY"):].splitlines()
+        assert sum("ragged-all-to-all(" in ln for ln in entry) == 1
+        assert " all-to-all(" not in text and "conditional(" not in text
+        assert not any(" reshape(" in ln and "bf16[" in ln for ln in entry)
+        dst_shard = 98304 * w * 2
+        temp = compiled.memory_analysis().temp_size_in_bytes
+        assert dst_shard <= temp < dst_shard * 1.01
+
+
 _FAMILIES = ["ring_allreduce", "ring_allgather", "ring_reduce_scatter",
              "bcast", "alltoall", "hbm_allreduce", "hbm_allgather",
              "hbm_reduce_scatter", "hbm_bcast", "hbm_alltoall"]

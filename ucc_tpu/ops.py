@@ -24,6 +24,7 @@ semantically exact, one extra HBM pass, only used by exotic ops.
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional, Sequence
 
 import jax
@@ -185,49 +186,133 @@ def allgatherv(x, counts, axis_name: str = "r"):
     return rows[jnp.asarray(idx, dtype=jnp.int32)]   # (sum(counts),)
 
 
-def a2av_index_maps(srows, drows):
-    """Static pack/unpack index maps for alltoallv — ONE home for the
-    subtle part, shared by ``ops.alltoallv`` and the TL/XLA program
-    builder. ``srows[i] = (scounts, sdispls)`` describes rank i's send
-    layout; ``drows[i]`` its recv layout (displacements may have gaps).
-    Returns (pidx, uidx, maxblk, max_src, max_span) where
-    PIDX[i][p*maxblk+j] = sdispl_i[p]+j and, over the exchanged rows
-    (row p = data from rank p), UIDX[i][ddispl_i[p]+j] = p*maxblk+j
-    (-1 = padding)."""
+#: widest row, in elements, that alltoallv planning groups elements into
+A2AV_MAX_ROW = 2048
+
+
+def a2av_row_width(values, cap: int = A2AV_MAX_ROW) -> int:
+    """The largest power of two, up to ``cap``, that divides every value:
+    the row width an alltoallv moves its blocks in. Any MoE routing at
+    hidden size 2048 lands on rows of 2048; arbitrary element counts on
+    narrower rows, down to single elements."""
+    g = 0
+    for v in values:
+        g = math.gcd(g, int(v))
+    return cap if g == 0 else min(g & -g, cap)
+
+
+def a2av_plan(scounts, sdispls, rcounts, rdispls, w: int, src_cap: int,
+              dst_cap: int):
+    """The runtime arrays of one alltoallv, in rows of ``w`` elements.
+
+    ``scounts[r][p]`` elements go from rank r to rank p, starting at
+    ``sdispls[r][p]`` of r's source; ``rcounts[r][p]`` elements arrive at
+    rank r from rank p, at ``rdispls[r][p]`` of r's destination. Returns
+    int32 ``(n, 4, n)``: per rank, ``lax.ragged_all_to_all``'s
+    input_offsets, send_sizes, output_offsets (where the block lands in
+    the receiver's buffer) and recv_sizes. Raises ValueError for counts
+    that do not match across ranks, that ``w`` does not divide, or that
+    reach outside a buffer of ``src_cap``/``dst_cap`` elements."""
     import numpy as np
-    n = len(srows)
-    maxblk = max((c for sc, _ in srows for c in sc), default=1) or 1
-    max_src = max((sum(sc) for sc, _ in srows), default=1) or 1
-    max_span = max((max((dd[p] + dc[p] for p in range(n)), default=0)
-                    for dc, dd in drows), default=1) or 1
-    pidx = np.full((n, n * maxblk), -1, dtype=np.int32)
-    for r, (sc, sd) in enumerate(srows):
-        for p in range(n):
-            pidx[r, p * maxblk:p * maxblk + sc[p]] = \
-                np.arange(sd[p], sd[p] + sc[p])
-    uidx = np.full((n, max_span), -1, dtype=np.int32)
-    for r, (dc, dd) in enumerate(drows):
-        for p in range(n):
-            uidx[r, dd[p]:dd[p] + dc[p]] = \
-                np.arange(p * maxblk, p * maxblk + dc[p])
-    return pidx, uidx, maxblk, max_src, max_span
+    sc = np.asarray(scounts, np.int64)
+    sd = np.asarray(sdispls, np.int64)
+    rc = np.asarray(rcounts, np.int64)
+    rd = np.asarray(rdispls, np.int64)
+    n = sc.shape[0]
+    if any(a.shape != (n, n) for a in (sd, rc, rd)):
+        raise ValueError("alltoallv: every rank needs n counts and "
+                         "displacements")
+    if (rc != sc.T).any():
+        raise ValueError("alltoallv: receive counts do not match the "
+                         "senders' counts")
+    if ((sc | sd | rd) % w).any():
+        raise ValueError(f"alltoallv: rows of {w} do not divide the "
+                         "counts and displacements")
+    if (sc < 0).any() or (sd < 0).any() or (rd < 0).any() or \
+            (sd + sc > src_cap).any() or (rd + rc > dst_cap).any():
+        raise ValueError("alltoallv: a block lies outside its buffer")
+    plan = np.empty((n, 4, n), np.int32)
+    plan[:, 0] = sd // w
+    plan[:, 1] = sc // w
+    plan[:, 2] = rd.T // w
+    plan[:, 3] = rc // w
+    return plan
 
 
-def a2av_exchange(x, pidx_c, uidx_c, n: int, maxblk: int, max_src: int,
-                  axis_name: str = "r"):
-    """The in-jit alltoallv body over prebuilt index maps: mask-pack,
-    all_to_all, mask-unpack (shared with the TL/XLA program)."""
-    me = lax.axis_index(axis_name)
-    flat = jnp.ravel(x)
-    if flat.size < max_src:
-        flat = jnp.pad(flat, (0, max_src - flat.size))
-    pi = pidx_c[me]
-    packed = jnp.where(pi >= 0, flat[jnp.clip(pi, 0, max_src - 1)], 0)
-    y = lax.all_to_all(packed.reshape(n, maxblk), axis_name,
-                       split_axis=0, concat_axis=0, tiled=False)
-    rows = y.reshape(n * maxblk)
-    ui = uidx_c[me]
-    return jnp.where(ui >= 0, rows[jnp.clip(ui, 0, n * maxblk - 1)], 0)
+def a2av_rows(flat, w: int):
+    """A flat buffer as rows of ``w`` elements. A multiple of 128 is
+    viewed as ``(rows, w // 128, 128)``: on a TPU that is a bitcast of
+    the 1-D layout for 16-bit rows of 2048 (and 32-bit rows of 1024),
+    where ``(rows, w)`` is a relayout copy in and out."""
+    tail = (w // 128, 128) if w % 128 == 0 else (w,)
+    return flat.reshape((flat.shape[0] // w,) + tail)
+
+
+def _take_rows(x, idx, keep, fill):
+    """``x[idx]`` where ``keep``, else ``fill`` (rows of x)."""
+    keep = keep.reshape(keep.shape + (1,) * (x.ndim - 1))
+    return jnp.where(keep, x[idx], fill)
+
+
+def ragged_all_to_all_portable(x, out, input_offsets, send_sizes,
+                               output_offsets, recv_sizes, *,
+                               axis_name: str = "r"):
+    """``lax.ragged_all_to_all``'s semantics from gathers and one
+    ``lax.all_to_all``, for platforms without the op (XLA:CPU). The row
+    indices are computed in the program from the same runtime arrays, so
+    the program is the same for every routing; every block is padded to
+    the source's length, so the exchange moves n times the source."""
+    n = input_offsets.shape[0]
+    rows = x.shape[0]
+    j = lax.iota(jnp.int32, rows)
+    src = input_offsets[:, None] + j
+    packed = _take_rows(x, jnp.clip(src, 0, rows - 1),
+                        j < send_sizes[:, None], 0)
+    got = lax.all_to_all(packed, axis_name, 0, 0, tiled=False)
+    at = lax.all_to_all(output_offsets, axis_name, 0, 0, tiled=True)
+    rel = lax.iota(jnp.int32, out.shape[0]) - at[:, None]
+    hit = (rel >= 0) & (rel < recv_sizes[:, None])
+    # the highest sender wins an overlap, as ragged_all_to_all's
+    # in-order writes do
+    flat = jnp.max(jnp.where(hit, lax.iota(jnp.int32, n)[:, None] * rows
+                             + rel, -1), axis=0)
+    return _take_rows(got.reshape((n * rows,) + x.shape[1:]),
+                      jnp.maximum(flat, 0), flat >= 0, out)
+
+
+def ragged_all_to_all_rows(x, out, plan, axis_name: str = "r"):
+    """Rows of ``x`` into ``out`` across ``axis_name`` as ``plan`` (this
+    rank's ``(4, n)`` slice of ``a2av_plan``) says, leaving the rest of
+    ``out`` as it is: ``lax.ragged_all_to_all`` on a TPU, which moves only
+    the real rows, the portable body elsewhere."""
+    args = (x, out, plan[0], plan[1], plan[2], plan[3])
+    return lax.platform_dependent(
+        *args,
+        tpu=functools.partial(lax.ragged_all_to_all, axis_name=axis_name),
+        default=functools.partial(ragged_all_to_all_portable,
+                                  axis_name=axis_name))
+
+
+def place_rows(x, out, src_off, size, dst_off):
+    """One rank's alltoallv: rows ``[src_off, src_off + size)`` of ``x``
+    written at row ``dst_off`` of ``out``, the rest of ``out`` kept. The
+    offsets and size may be traced, so one program serves every count.
+    Where the rows stay in place (equal shapes and offsets) it is one
+    select over the buffer; a shift gathers the rows first."""
+    def mask(a):
+        i = lax.broadcasted_iota(jnp.int32, a.shape, 0)
+        return (i >= dst_off) & (i < dst_off + size)
+
+    def shifted(x, out):
+        rel = lax.iota(jnp.int32, out.shape[0]) - dst_off
+        moved = x[jnp.clip(rel + src_off, 0, x.shape[0] - 1)]
+        return jnp.where(mask(moved), moved, out)
+
+    if x.shape != out.shape:
+        return shifted(x, out)
+    return lax.cond(src_off == dst_off,
+                    lambda x, out: jnp.where(mask(out), x, out), shifted,
+                    x, out)
 
 
 def alltoallv(x, counts, axis_name: str = "r"):
@@ -239,23 +324,26 @@ def alltoallv(x, counts, axis_name: str = "r"):
     for ranks 0..n-1 back to back (cumsum displacements), padded to
     ``max_i sum_j counts[i][j]`` elements; the return value is the recv
     buffer in the same packed layout (blocks from ranks 0..n-1), padded
-    to ``max_j sum_i counts[i][j]``. XLA sees only static shapes: the
-    per-rank pack/unpack index maps are computed at trace time and
-    selected by ``axis_index`` inside the program."""
+    with zeros to ``max_j sum_i counts[i][j]``. The counts become a
+    constant ``(n, 4, n)`` plan (``a2av_plan``) that ``axis_index``
+    selects from, and ``ragged_all_to_all_rows`` moves the rows."""
     import numpy as np
     m = np.asarray(counts, dtype=np.int64)
     n = m.shape[0]
-    sdispl = np.zeros((n, n), dtype=np.int64)
-    sdispl[:, 1:] = np.cumsum(m, axis=1)[:, :-1]
-    rdispl = np.zeros((n, n), dtype=np.int64)
-    rdispl[1:, :] = np.cumsum(m, axis=0)[:-1, :]
-    srows = [([int(c) for c in m[i]], [int(d) for d in sdispl[i]])
-             for i in range(n)]
-    drows = [([int(m[p, i]) for p in range(n)],
-              [int(rdispl[p, i]) for p in range(n)]) for i in range(n)]
-    pidx, uidx, maxblk, max_src, _ = a2av_index_maps(srows, drows)
-    return a2av_exchange(x, jnp.asarray(pidx), jnp.asarray(uidx), n,
-                         maxblk, max_src, axis_name)
+    sd = np.cumsum(m, axis=1) - m          # sender's displacements
+    rd = np.cumsum(m, axis=0) - m          # rd[p, i]: p's block at i
+    max_src = max(1, int(m.sum(axis=1).max()))
+    max_dst = max(1, int(m.sum(axis=0).max()))
+    w = a2av_row_width(np.concatenate(
+        [m.ravel(), sd.ravel(), rd.ravel(), [max_src, max_dst]]))
+    plan = a2av_plan(m, sd, m.T, rd.T, w, max_src, max_dst)
+    flat = jnp.ravel(x)[:max_src]
+    if flat.size < max_src:
+        flat = jnp.pad(flat, (0, max_src - flat.size))
+    out = jnp.zeros((max_dst,), flat.dtype)
+    mine = jnp.asarray(plan)[lax.axis_index(axis_name)]
+    return ragged_all_to_all_rows(a2av_rows(flat, w), a2av_rows(out, w),
+                                  mine, axis_name).reshape(-1)
 
 
 def bcast(x, root: int, axis_name: str = "r"):

@@ -7,6 +7,7 @@ N-rank ones. Also serves as the service team for 1-rank teams.
 """
 from __future__ import annotations
 
+import functools
 from typing import Any, List, Optional
 
 import numpy as np
@@ -18,7 +19,7 @@ from ..core.components import (BaseContext, BaseLib, TransportLayer,
 from ..schedule.task import CollTask
 from ..score.score import CollScore
 from ..status import Status, UccError
-from .base import TlTeamBase, binfo_u8
+from .base import TlTeamBase, binfo_typed, binfo_u8
 
 SUPPORTED = COLL_TYPE_ALL  # tl_self.h:78-85
 
@@ -34,7 +35,10 @@ class TlSelfTask(CollTask):
         args = self.args
         if not args.is_inplace and args.src is not None and \
                 args.dst is not None and args.src.buffer is not None:
-            if args.dst.mem_type == MemoryType.TPU:
+            if isinstance(args.src, BufferInfoV) or \
+                    isinstance(args.dst, BufferInfoV):
+                _place_v(args.src, args.dst)
+            elif args.dst.mem_type == MemoryType.TPU:
                 # TPU buffer convention: jax.Arrays are immutable, the
                 # result is delivered by rebinding dst.buffer (see tl/xla).
                 # 1-rank semantics: result == src.
@@ -50,6 +54,61 @@ class TlSelfTask(CollTask):
                 dst_u8[:n] = src_u8[:n]
         self.status = Status.OK
         return Status.OK
+
+
+def _block(bi):
+    """(displacement, count) of rank 0's block of a buffer info."""
+    if isinstance(bi, BufferInfoV):
+        return (int(bi.displacements[0]) if bi.displacements is not None
+                else 0), int(bi.counts[0])
+    return 0, int(bi.count)
+
+
+def _place_v(src, dst) -> None:
+    """A v-collective on one rank: the source block lands at the
+    destination's displacement, inside the caller's ``dst.buffer`` when
+    one is given (the rest of it kept), else in zeros up to the block's
+    end. On the device the offsets and count are one runtime int32 input
+    (a single transfer) of one program per buffer shape
+    (``ops.place_rows``)."""
+    s_off, s_cnt = _block(src)
+    d_off, d_cnt = _block(dst)
+    count = min(s_cnt, d_cnt)
+    if dst.mem_type != MemoryType.TPU:
+        if dst.buffer is not None:
+            out = binfo_typed(dst, count=d_off + count)
+            out[d_off:] = binfo_typed(src, count=count, elem_offset=s_off)
+        return
+    import jax
+    import jax.numpy as jnp
+
+    from ..ops import a2av_row_width
+    buf = src.buffer
+    if src.mem_type != MemoryType.TPU:
+        buf = jax.device_put(np.asarray(buf))
+    x = jnp.ravel(buf) if buf.ndim != 1 else buf
+    if dst.buffer is None and s_off == d_off == 0 and count == x.size:
+        dst.buffer = x         # the whole source, where it stands
+        return
+    out = dst.buffer if dst.buffer is not None else \
+        jnp.zeros(d_off + count, x.dtype, device=x.device)
+    flat = jnp.ravel(out) if out.ndim != 1 else out
+    w = a2av_row_width([s_off, d_off, count, x.size, flat.size])
+    placed = _place_fn(w)(x, flat, np.array(
+        [s_off // w, count // w, d_off // w], np.int32))
+    dst.buffer = placed.reshape(out.shape) if out.ndim != 1 else placed
+
+
+@functools.lru_cache(maxsize=None)
+def _place_fn(w: int):
+    import jax
+
+    from .. import ops
+
+    def self_place(x, out, rows):     # rows: source row, count, dst row
+        return ops.place_rows(ops.a2av_rows(x, w), ops.a2av_rows(out, w),
+                              rows[0], rows[1], rows[2]).reshape(-1)
+    return jax.jit(self_place)
 
 
 class _SelfServiceTask(CollTask):

@@ -28,7 +28,7 @@ from __future__ import annotations
 
 import pickle
 import threading
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Dict, List, NamedTuple, Optional, Tuple
 
 import numpy as np
 
@@ -225,6 +225,9 @@ class XlaTeamShared:
                 # alg_table entry.
                 self._launch_rooted(slot, proto)
                 return
+            if proto.coll == CollType.ALLTOALLV:
+                self._launch_a2av(slot, proto)
+                return
             bufs = tuple(buf for _, (buf, _t) in sorted(slot.items()))
             cached = self.launch_cache.get(proto.tag)
             if cached is not None and len(cached[0]) == len(bufs) and \
@@ -315,6 +318,52 @@ class XlaTeamShared:
                     # the error — finish them here or test() spins forever
                     task._fast_round = False
                     task.super_status = Status.ERR_NO_MESSAGE
+
+    # ------------------------------------------------------------------
+    def _launch_a2av(self, slot, proto) -> None:
+        """Alltoallv as one program per buffer shape: the counts travel
+        as a sharded int32 ``(n, 4, n)`` input (``ops.a2av_plan``), so a
+        new routing reuses the compiled program. The caller's ``dst``
+        buffers, when given, are the program's output operand: the
+        result keeps their contents outside the received blocks."""
+        import jax
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
+        tok = profiling.begin("ucc.xla.a2av.plan")
+        plan = proto.a2av_plan(slot)
+        sharding = NamedSharding(self.mesh, P("r"))
+        table = plan.table.reshape(-1)
+        if self.n_local == len(self.devices):
+            tarr = jax.device_put(table, sharding)
+        else:    # each controller places its own ranks' rows
+            tarr = jax.make_array_from_callback(
+                table.shape, sharding, lambda idx: table[idx])
+        if tok is not None:
+            profiling.end(tok)
+        program = proto.a2av_program(self, plan)
+        n = len(self.devices)
+        tok = profiling.begin("ucc.xla.stage")
+        srcs, dsts = [], []
+        for rank, (buf, task) in sorted(slot.items()):
+            dev = self.devices[rank]
+            srcs.append(jax.device_put(
+                task.shard_for_launch(buf, plan.src_cap), dev))
+            if plan.given:
+                dsts.append(task.a2av_dst_shard(plan.dst_cap, dev))
+        args = [jax.make_array_from_single_device_arrays(
+            (n * plan.src_cap,), sharding, srcs)]
+        if plan.given:
+            args.append(jax.make_array_from_single_device_arrays(
+                (n * plan.dst_cap,), sharding, dsts))
+        if tok is not None:
+            profiling.end(tok)
+        tok = profiling.begin("ucc.xla.dispatch")
+        out = program(*args, tarr)
+        if tok is not None:
+            profiling.end(tok)
+        by_dev = {s.device: s.data for s in out.addressable_shards}
+        for rank, (_, task) in slot.items():
+            task.set_result(out, by_dev)
 
     # ------------------------------------------------------------------
     def _launch_rooted(self, slot, proto) -> None:
@@ -802,18 +851,10 @@ class XlaCollTask(CollTask):
         return flat   # 1-D shard, used as-is
 
     def build_program(self, shared: XlaTeamShared, slot=None):
-        """Compiled shard_map program + padded per-rank count (cached).
-
-        For ALLTOALLV the per-pair counts matrix is assembled from the
-        rendezvous slot (every local task's args) when all team ranks are
-        process-local; teams SPANNING processes exchange the vectors over
-        the service team first (post_fn), so every controller compiles
-        the identical program from the identical table.
-        """
+        """Compiled shard_map program + padded per-rank count (cached;
+        each miss is one ``ucc.xla.build`` span)."""
         args = self.args
         n = len(shared.devices)
-        if self.coll == CollType.ALLTOALLV:
-            return self._build_a2av_program(shared, slot)
         count = self.src_count()
         key = (self.coll, args.op, self.np_dtype.str, count, self.alg,
                int(args.root) if args.is_rooted else 0, self._vkey())
@@ -824,10 +865,13 @@ class XlaCollTask(CollTask):
         cached = shared.programs.get(key)
         if cached is not None:
             return cached
+        tok = profiling.begin("ucc.xla.build")
         program, padded = _build_xla_program(
             shared.mesh, n, self.coll, args, self.np_dtype, count, self.alg,
             qblock=self._qblock)
         shared.programs[key] = (program, padded)
+        if tok is not None:
+            profiling.end(tok)
         return program, padded
 
     def _vkey(self):
@@ -837,68 +881,73 @@ class XlaCollTask(CollTask):
         return None
 
     # -- alltoallv ------------------------------------------------------
-    def _build_a2av_program(self, shared: XlaTeamShared, slot):
-        """Pack -> all_to_all -> unpack, ALL inside the jitted body via
-        static per-rank gather-index maps derived from the slot-assembled
-        counts/displacements (no eager per-rank dispatches — the same rule
-        the 1-D shard layout enforces). The input shard is the rank's raw
-        flat src padded to the max send total; the output shard is the
-        rank's dst layout (with displacement gaps) padded to the max span.
-        """
-        import jax
-        import jax.numpy as jnp
-        from jax.sharding import PartitionSpec as P
-
-        n = len(shared.devices)
-
+    def a2av_vecs(self) -> tuple:
+        """This rank's alltoallv layout: (scounts, sdispls, rcounts,
+        rdispls, source length, destination buffer length or None);
+        None displacements are dense."""
         from ..utils.mathutils import default_displs
+        src, dst = self.args.src, self.args.dst
 
-        def _vec(bi):
+        def vec(bi):
             counts = [int(c) for c in bi.counts]
-            if bi.displacements is not None:
-                displs = [int(d) for d in bi.displacements]
-            else:
-                displs = default_displs(counts)
+            displs = default_displs(counts) if bi.displacements is None \
+                else [int(d) for d in bi.displacements]
             return counts, displs
 
-        if self._a2av_table is not None:
-            # spanning team: vectors came from the service-team exchange
-            # (one entry per TEAM rank, identical in every process — the
-            # compiled program must be bit-identical across controllers)
-            rows = [(list(sc), list(sd) if sd is not None
-                     else default_displs(list(sc)))
-                    for sc, sd, _, _ in self._a2av_table]
-            dsts = [(list(dc), list(dd) if dd is not None
-                     else default_displs(list(dc)))
-                    for _, _, dc, dd in self._a2av_table]
-        else:
-            rows = []      # per src rank: (scounts, sdispls)
-            for r in sorted(slot):
-                rows.append(_vec(slot[r][1].args.src))
-            dsts = [_vec(slot[r][1].args.dst) for r in sorted(slot)]
-        key = (self.coll, self.np_dtype.str,
-               tuple((tuple(c), tuple(d)) for c, d in rows),
-               tuple((tuple(c), tuple(d)) for c, d in dsts))
-        cached = shared.programs.get(key)
-        if cached is not None:
-            return cached
+        sbuf = self.local_src()
+        given = dst.mem_type == MemoryType.TPU and dst.buffer is not None
+        return (*vec(src), *vec(dst), int(np.prod(sbuf.shape)),
+                int(np.prod(dst.buffer.shape)) if given else None)
 
-        # the index-map construction + exchange body live in ops (shared
-        # with the public in-jit ops.alltoallv)
-        from ..ops import a2av_exchange, a2av_index_maps
-        pidx, uidx, maxblk, max_src, _ = a2av_index_maps(rows, dsts)
-        pidx_c = jnp.asarray(pidx)
-        uidx_c = jnp.asarray(uidx)
+    def a2av_plan(self, slot) -> "A2avPlan":
+        """The launch's shapes and runtime arrays, from the rendezvous
+        slot or, on a team spanning processes, from the table exchanged
+        over the service team (identical in every process, so every
+        controller builds the same program)."""
+        from .. import ops
+        rows = self._a2av_table if self._a2av_table is not None else \
+            [slot[r][1].a2av_vecs() for r in sorted(slot)]
+        sc, sd, rc, rd, src_lens, dst_lens = zip(*rows)
+        spans = [max((d + c for c, d in zip(cs, ds)), default=0)
+                 for cs, ds in zip(rc, rd)]
+        src_cap = max(max(src_lens), 1)
+        given = any(d is not None for d in dst_lens)
+        dst_cap = max(d if d is not None else s
+                      for d, s in zip(dst_lens, spans))
+        flat = [v for vecs in (sc, sd, rc, rd) for vec in vecs for v in vec]
+        w = ops.a2av_row_width(flat + [src_cap] + ([dst_cap] if given
+                                                    else []))
+        if not given:
+            dst_cap = -(-_ladder(dst_cap) // w) * w
+        try:
+            table = ops.a2av_plan(sc, sd, rc, rd, w, src_cap, dst_cap)
+        except ValueError as e:
+            raise UccError(Status.ERR_INVALID_PARAM, str(e)) from None
+        return A2avPlan(table, w, src_cap, dst_cap, given)
 
-        def body(x):                 # (max_src,) raw flat send buffer
-            return a2av_exchange(x, pidx_c, uidx_c, n, maxblk, max_src)
+    def a2av_program(self, shared: XlaTeamShared, plan: "A2avPlan"):
+        """The compiled alltoallv of this buffer shape (cached; each miss
+        is one ``ucc.xla.build`` span)."""
+        key = (self.coll, self.np_dtype.str, self.alg, plan.w,
+               plan.src_cap, plan.dst_cap, plan.given)
+        program = shared.programs.get(key)
+        if program is None:
+            tok = profiling.begin("ucc.xla.build")
+            program = shared.programs[key] = _build_a2av_program(
+                shared.mesh, len(shared.devices), plan, self.alg)
+            if tok is not None:
+                profiling.end(tok)
+        return program
 
-        body.__name__ = f"ucc_alltoallv_{self.alg}"
-        program = jax.jit(jax.shard_map(body, mesh=shared.mesh,
-                                        in_specs=P("r"),
-                                        out_specs=P("r"), check_vma=False))
-        shared.programs[key] = (program, max_src)
-        return program, max_src
+    def a2av_dst_shard(self, dst_cap: int, dev):
+        """This rank's destination as the program's output operand:
+        the caller's buffer, or zeros where this rank gave none."""
+        import jax
+        import jax.numpy as jnp
+        buf = self.args.dst.buffer
+        if buf is None or self.args.dst.mem_type != MemoryType.TPU:
+            return jnp.zeros(dst_cap, self.np_dtype, device=dev)
+        return jax.device_put(self.shard_for_launch(buf, dst_cap), dev)
 
     # -- lifecycle --------------------------------------------------------
     def post_fn(self) -> Status:
@@ -913,7 +962,7 @@ class XlaCollTask(CollTask):
         if self.coll == CollType.ALLTOALLV and \
                 shared.n_local < len(shared.devices) and \
                 self._a2av_table is None:
-            # spanning team: the compiled program's static index maps need
+            # spanning team: the program's shapes and runtime arrays need
             # EVERY rank's counts/displacements, but the rendezvous slot
             # only covers local ranks — exchange the vectors over the
             # service team first (nonblocking; the tl_nccl-style
@@ -926,14 +975,8 @@ class XlaCollTask(CollTask):
                     not hasattr(svc_team, "service_allgather"):
                 self.status = Status.ERR_NOT_SUPPORTED
                 return Status.OK
-            args = self.args
-            vecs = ([int(c) for c in args.src.counts],
-                    None if args.src.displacements is None else
-                    [int(d) for d in args.src.displacements],
-                    [int(c) for c in args.dst.counts],
-                    None if args.dst.displacements is None else
-                    [int(d) for d in args.dst.displacements])
-            svc = svc_team.service_allgather(pickle.dumps(vecs))
+            svc = svc_team.service_allgather(
+                pickle.dumps(self.a2av_vecs()))
             svc.post()
             self._a2av_svc = svc
             return Status.OK
@@ -1148,24 +1191,24 @@ class XlaCollTask(CollTask):
         view[:] = row[off:off + view.size]
 
     def _a2av_copy_out(self) -> None:
-        n = self.tl_team.size
         dstv = self.args.dst
-        from ..utils.mathutils import default_displs
-        rcounts = [int(c) for c in dstv.counts]
-        rdispls = [int(d) for d in dstv.displacements] \
-            if dstv.displacements is not None else \
-            default_displs(rcounts)
-        dst_span = max((rdispls[p] + rcounts[p] for p in range(n)),
-                       default=0)
+        _, _, rcounts, rdispls, _, dst_len = self.a2av_vecs()
+        span = max((d + c for c, d in zip(rcounts, rdispls)), default=0)
         if dstv.mem_type == MemoryType.TPU:
             out = self._my_out_jax()
-            dstv.buffer = out[:dst_span] if out.shape[-1] != dst_span \
-                else out
-            self.result_array = dstv.buffer
+            # no buffer given: the span, cut from a capacity of the ladder
+            want = span if dst_len is None else dst_len
+            if out.shape[0] != want:
+                out = out[:want]
+            if dst_len is not None and dstv.buffer.ndim != 1:
+                out = out.reshape(dstv.buffer.shape)
+            dstv.buffer = out
+            self.result_array = out
             return
         row = self._my_out_np()
-        view = binfo_typed(dstv, count=dst_span)
-        view[:] = row[:dst_span]
+        view = binfo_typed(dstv, count=span)
+        for c, d in zip(rcounts, rdispls):   # the blocks only
+            view[d:d + c] = row[d:d + c]
 
     def _unpad_jax(self, out, dst) -> Any:
         want = int(dst.count) if isinstance(dst, BufferInfo) else \
@@ -1278,6 +1321,51 @@ def _build_xla_program(mesh, n: int, coll: CollType, args, nd, count: int,
                                     in_specs=in_specs,
                                     out_specs=out_specs, check_vma=False))
     return program, padded
+
+
+class A2avPlan(NamedTuple):
+    """One alltoallv launch: the runtime arrays and the shapes the
+    program is keyed on (capacities in elements, rows of ``w``)."""
+    table: np.ndarray         # int32 (n, 4, n), ops.a2av_plan
+    w: int
+    src_cap: int
+    dst_cap: int
+    given: bool               # the callers' dst buffers are the operand
+
+
+def _ladder(span: int) -> int:
+    """The destination capacity for a caller that gave no buffer: the
+    span rounded up to four sizes per octave (at most 25 % above it), so
+    callers whose spans move every step reuse a handful of programs."""
+    if span <= 8:
+        return max(span, 1)
+    step = 1 << (span - 1).bit_length() - 3
+    return -(-span // step) * step
+
+
+def _build_a2av_program(mesh, n: int, plan: A2avPlan, alg: str):
+    """shard_map of ``ops.ragged_all_to_all_rows`` over flat shards
+    viewed as rows of ``plan.w``; inputs: source, [destination,] plan."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import PartitionSpec as P
+
+    from .. import ops
+    w, dst_cap = plan.w, plan.dst_cap
+
+    def body(x, *rest):
+        *dst, t = rest
+        out = dst[0] if dst else jnp.zeros((dst_cap,), x.dtype)
+        return ops.ragged_all_to_all_rows(
+            ops.a2av_rows(x, w), ops.a2av_rows(out, w),
+            t.reshape(4, n)).reshape(-1)
+
+    # names the program in profiles (``jit_ucc_alltoallv_xla``)
+    body.__name__ = f"ucc_alltoallv_{alg}"
+    return jax.jit(jax.shard_map(body, mesh=mesh,
+                                 in_specs=(P("r"),) * (3 if plan.given
+                                                       else 2),
+                                 out_specs=P("r"), check_vma=False))
 
 
 # ---------------------------------------------------------------------------
