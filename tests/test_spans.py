@@ -176,6 +176,91 @@ def test_persistent_repost_skips_staging(job4, tmp_path):
         rq.finalize()
 
 
+def _placement_args(job, r, where):
+    """Rank ``r``'s AVG allreduce of ``r + 1`` as placement ``where``
+    gives it: device-resident in place, a host numpy source, or (rank 0
+    alone) a source committed to rank 1's device."""
+    x = np.full(COUNT, r + 1.0, np.float32)
+    dst = BufferInfo(None, COUNT, DataType.FLOAT32, mem_type=MemoryType.TPU)
+    if where == "device":
+        dst.buffer = _dev(job, r, x)
+        return CollArgs(coll_type=CollType.ALLREDUCE,
+                        src=BufferInfo(None, COUNT, DataType.FLOAT32,
+                                       mem_type=MemoryType.TPU),
+                        dst=dst, op=ReductionOp.AVG,
+                        flags=CollArgsFlags.IN_PLACE)
+    if where == "host":
+        src = BufferInfo(x, COUNT, DataType.FLOAT32,
+                         mem_type=MemoryType.HOST)
+    else:
+        src = BufferInfo(_dev(job, 1 if r == 0 else r, x), COUNT,
+                         DataType.FLOAT32, mem_type=MemoryType.TPU)
+    return CollArgs(coll_type=CollType.ALLREDUCE, src=src, dst=dst,
+                    op=ReductionOp.AVG)
+
+
+@pytest.mark.parametrize("where,placed", [
+    ("device", 0), ("host", 4), ("wrong_device", 1)])
+def test_staging_places_only_shards_not_in_place(job4, tmp_path, where,
+                                                  placed):
+    """A shard already on its rank's device goes into the global array
+    as it is; each other shard costs one ``ucc.xla.place``."""
+    job, teams = job4
+    n = len(teams)
+    argses = [_placement_args(job, r, where) for r in range(n)]
+    with _Capture(tmp_path) as cap:
+        reqs = job.run_coll(teams, lambda r: argses[r])
+    for rq, a in zip(reqs, argses):
+        assert rq.test() == Status.OK
+        np.testing.assert_allclose(np.asarray(a.dst.buffer), (n + 1) / 2)
+        rq.finalize()
+    tot = profiling.totals()
+    assert tot["ucc.xla.launch"][0] == tot["ucc.xla.stage"][0] == 1
+    assert tot.get("ucc.xla.place", (0,))[0] == placed
+    ev = _host_events(cap.path)
+    for child in ev.get("ucc.xla.place", []):
+        assert _inside(child, ev["ucc.xla.stage"])
+
+
+def test_alltoallv_device_shards_are_not_placed(job4, tmp_path):
+    """Device-resident alltoallv source and destination go into the
+    launch as they are, and the destination keeps its contents outside
+    the blocks it receives."""
+    from ucc_tpu import BufferInfoV
+    job, teams = job4
+    n = len(teams)
+    m = np.array([[1, 2, 0, 3], [2, 1, 4, 0], [0, 3, 1, 2], [1, 0, 2, 1]])
+    gap, fill = 2, -7.0
+    argses = []
+    for r in range(n):
+        sc = [int(c) for c in m[r]]
+        rc = [int(m[p][r]) for p in range(n)]
+        rd = [int(sum(rc[:p])) + gap * p for p in range(n)]
+        src = np.arange(sum(sc), dtype=np.float32) + 100 * r
+        dst = np.full(rd[-1] + rc[-1] + gap, fill, np.float32)
+        argses.append(CollArgs(
+            coll_type=CollType.ALLTOALLV,
+            src=BufferInfoV(_dev(job, r, src), sc, None, DataType.FLOAT32,
+                            mem_type=MemoryType.TPU),
+            dst=BufferInfoV(_dev(job, r, dst), rc, rd, DataType.FLOAT32,
+                            mem_type=MemoryType.TPU)))
+    with _Capture(tmp_path):
+        reqs = job.run_coll(teams, lambda r: argses[r])
+    tot = profiling.totals()
+    assert tot["ucc.xla.launch"][0] == tot["ucc.xla.stage"][0] == 1
+    assert "ucc.xla.place" not in tot
+    for r, (rq, a) in enumerate(zip(reqs, argses)):
+        assert rq.test() == Status.OK
+        out = np.asarray(a.dst.buffer)
+        want = np.full(out.shape, fill, np.float32)
+        for p in range(n):
+            c, d = int(m[p][r]), a.dst.displacements[p]
+            sd = int(m[p][:r].sum())
+            want[d:d + c] = np.arange(sd, sd + c, dtype=np.float32) + 100 * p
+        np.testing.assert_array_equal(out, want)
+        rq.finalize()
+
+
 def test_threads_lose_no_span(tmp_path):
     """Spans closed on many threads at once all reach ``totals()``."""
     import sys

@@ -127,6 +127,26 @@ _SHARED: Dict[Any, "XlaTeamShared"] = {}
 _SHARED_LOCK = threading.Lock()
 
 
+def _placed(x, dev):
+    """``x`` as a shard of a global array on ``dev``: as it is when it is
+    a ``jax.Array`` already in ``dev``'s default memory, else through one
+    ``jax.device_put`` (one ``ucc.xla.place`` span, so the span's count is
+    the number of shards that moved). A ``device_put`` of an array
+    already in place moves nothing but costs host dispatch work."""
+    import jax
+    from jax.sharding import SingleDeviceSharding
+    if isinstance(x, jax.Array):
+        s = x.sharding
+        if isinstance(s, SingleDeviceSharding) and s.device_set == {dev} \
+                and s.memory_kind in (None, dev.default_memory().kind):
+            return x
+    tok = profiling.begin("ucc.xla.place")
+    x = jax.device_put(x, dev)
+    if tok is not None:
+        profiling.end(tok)
+    return x
+
+
 class XlaTeamShared:
     def __init__(self, key, mesh, devices, n_local: int,
                  cache_max: int = 64):
@@ -272,8 +292,8 @@ class XlaTeamShared:
             tok = profiling.begin("ucc.xla.stage")
             shards = []
             for rank, (buf, task) in sorted(slot.items()):
-                row = task.shard_for_launch(buf, count_padded)
-                shards.append(jax.device_put(row, self.devices[rank]))
+                shards.append(_placed(task.shard_for_launch(
+                    buf, count_padded), self.devices[rank]))
             garr = jax.make_array_from_single_device_arrays(
                 global_shape, sharding, shards)
             if tok is not None:
@@ -346,8 +366,8 @@ class XlaTeamShared:
         srcs, dsts = [], []
         for rank, (buf, task) in sorted(slot.items()):
             dev = self.devices[rank]
-            srcs.append(jax.device_put(
-                task.shard_for_launch(buf, plan.src_cap), dev))
+            srcs.append(_placed(task.shard_for_launch(buf, plan.src_cap),
+                                dev))
             if plan.given:
                 dsts.append(task.a2av_dst_shard(plan.dst_cap, dev))
         args = [jax.make_array_from_single_device_arrays(
@@ -468,8 +488,8 @@ class XlaTeamShared:
                                                 check_vma=False))
                 self.programs[key] = program
             sharding = NamedSharding(self.mesh, P("r"))
-            shards = [jax.device_put(t.shard_for_launch(buf, padded),
-                                     self.devices[r])
+            shards = [_placed(t.shard_for_launch(buf, padded),
+                              self.devices[r])
                       for r, (buf, t) in sorted(slot.items())]
             garr = jax.make_array_from_single_device_arrays(
                 (n * padded,), sharding, shards)
@@ -500,16 +520,8 @@ class XlaTeamShared:
                 np.asarray(buf).reshape(-1)), root_dev)
                 for _, (buf, _t) in items]
             return jnp.concatenate(parts) if len(parts) > 1 else parts[0]
-        flats = []
-        for rank, (buf, _t) in items:
-            flat = jnp.ravel(buf) if buf.ndim != 1 else buf
-            try:
-                if flat.devices() != {self.devices[rank]}:
-                    # uncommitted/misplaced buffer: pin it first
-                    flat = jax.device_put(flat, self.devices[rank])
-            except Exception:  # noqa: BLE001 - non-array duck types
-                flat = jax.device_put(flat, self.devices[rank])
-            flats.append(flat)
+        flats = [_placed(jnp.ravel(buf) if buf.ndim != 1 else buf,
+                         self.devices[rank]) for rank, (buf, _t) in items]
         cnt = flats[0].shape[0]
         if any(f.shape[0] != cnt for f in flats):
             # match the non-rooted path's explicit diagnostic
@@ -942,12 +954,11 @@ class XlaCollTask(CollTask):
     def a2av_dst_shard(self, dst_cap: int, dev):
         """This rank's destination as the program's output operand:
         the caller's buffer, or zeros where this rank gave none."""
-        import jax
         import jax.numpy as jnp
         buf = self.args.dst.buffer
         if buf is None or self.args.dst.mem_type != MemoryType.TPU:
             return jnp.zeros(dst_cap, self.np_dtype, device=dev)
-        return jax.device_put(self.shard_for_launch(buf, dst_cap), dev)
+        return _placed(self.shard_for_launch(buf, dst_cap), dev)
 
     # -- lifecycle --------------------------------------------------------
     def post_fn(self) -> Status:
