@@ -383,11 +383,10 @@ class TestTpuStreamEe:
 
 
 class TestTriggeredAfterFastLane:
-    """Regression: a persistent device collective whose fast re-post lane
-    has been warmed (two plain posts) must still run the EE callback when
-    a later post is TRIGGERED — the fast lane never runs observers, so
-    the request must divert that round to the generic path (the cb is
-    attached between posts; core/coll.py re-checks observers per post)."""
+    """A persistent device collective re-posted twice must still run the
+    EE callback when a later post is TRIGGERED: the EE attaches its cb
+    between posts, and every re-post runs the full lifecycle, which
+    runs it."""
 
     def test_triggered_post_after_warm_reposts(self):
         jax = pytest.importorskip("jax")
@@ -415,7 +414,7 @@ class TestTriggeredAfterFastLane:
                     op=ReductionOp.SUM,
                     flags=CollArgsFlags.PERSISTENT))
                 reqs.append(teams[r].collective_init(argses[r]))
-            # two plain rounds: the second probes + arms the fast lane
+            # two plain rounds before the triggered one
             for _ in range(2):
                 for rq in reqs:
                     rq.post()
@@ -429,8 +428,7 @@ class TestTriggeredAfterFastLane:
                 for ev in evs:
                     ev.set()
                 deadline = _time.monotonic() + 20
-                # the EE completion event must arrive (cb ran) — the bug
-                # was a silent fast_repost that skipped the cb forever
+                # the EE completion event must arrive (cb ran)
                 got = [False] * n
                 while not all(got):
                     for r in range(n):

@@ -518,84 +518,20 @@ class TestFallback:
 
 
 # ---------------------------------------------------------------------------
-# launch-cache bound (ISSUE 15 satellite)
+# per-team shared state is dropped at team destroy
 # ---------------------------------------------------------------------------
 
-class TestLaunchCacheBounds:
-    def test_eviction_and_destroy_clear_unit(self):
-        """The bound + clear semantics on a bare XlaTeamShared: oldest
-        evicted at the cap, replace-in-place exempt, refcount-0 put()
-        drops every cache."""
+class TestTeamSharedClear:
+    def test_destroy_clears_programs_and_pending_unit(self):
+        """A refcount-0 put() on a bare XlaTeamShared drops the compiled
+        programs and the pending rendezvous slots."""
         from ucc_tpu.tl.xla import XlaTeamShared
-        s = XlaTeamShared(object(), None, [], 1, cache_max=4)
-        for i in range(8):
-            s._cache_insert(s.launch_cache, i, f"v{i}")
-            s._cache_insert(s.aot_programs, i, f"a{i}")
-        assert list(s.launch_cache) == [4, 5, 6, 7]
-        assert len(s.aot_programs) == 4
-        # replacing a live key must not evict an unrelated entry
-        s._cache_insert(s.launch_cache, 5, "v5b")
-        assert list(s.launch_cache) == [4, 5, 6, 7]
-        assert s.launch_cache[5] == "v5b"
+        s = XlaTeamShared(object(), None, [], 1)
         s.programs["p"] = "x"
+        s.pending[1] = {0: ("shard", None)}
         s.refcount = 1
         s.put()
-        assert not s.launch_cache and not s.aot_programs \
-            and not s.programs
-
-    def test_bounded_and_cleared(self):
-        os.environ["UCC_TL_XLA_LAUNCH_CACHE_MAX"] = "4"
-        j = UccJob(2)
-        try:
-            tms = j.create_team()
-            shared = next(t for t in tms[0].cl_teams[0].tl_teams
-                          if t.name == "xla").shared
-            # the shared object can be a REUSED one when an earlier
-            # test leaked a team with the same (ranks, host, pid) key;
-            # the bound below then checks against ITS cap
-            fresh = shared.cache_max == 4
-            reqs_all = []
-            for i in range(8):
-                count = 32 + 8 * i     # distinct shapes -> distinct
-                argses = []            # programs + tags
-                for r in range(2):
-                    argses.append(CollArgs(
-                        coll_type=CollType.ALLREDUCE,
-                        src=dev_buf(j, r, np.ones(count, np.float32),
-                                    DataType.FLOAT32),
-                        dst=BufferInfo(None, count, DataType.FLOAT32,
-                                       mem_type=MemoryType.TPU),
-                        op=ReductionOp.SUM,
-                        flags=CollArgsFlags.PERSISTENT))
-                reqs = [tms[r].collective_init(argses[r])
-                        for r in range(2)]
-                for rq in reqs:
-                    rq.post()
-                j.progress_until(lambda: all(
-                    rq.test() != Status.IN_PROGRESS for rq in reqs))
-                assert all(rq.test() == Status.OK for rq in reqs)
-                reqs_all.append(reqs)
-            # per-team caches stay bounded at the (configured) cap
-            assert len(shared.launch_cache) <= shared.cache_max
-            assert len(shared.aot_programs) <= shared.cache_max
-            if fresh:
-                assert len(shared.launch_cache) <= 4
-            for reqs in reqs_all:
-                for rq in reqs:
-                    rq.finalize()
-            for t in tms:
-                t.destroy()
-            if shared.refcount <= 0:
-                # team destroy cleared every cached executable + pinned
-                # array (skipped when a leaked same-key team still
-                # holds a reference)
-                assert not shared.launch_cache
-                assert not shared.aot_programs
-                assert not shared.programs
-            j.teams.clear()
-        finally:
-            j.cleanup()
-            os.environ.pop("UCC_TL_XLA_LAUNCH_CACHE_MAX", None)
+        assert not s.programs and not s.pending
 
 
 # ---------------------------------------------------------------------------

@@ -138,20 +138,42 @@ class TestThreadTreeOob:
 
 
 class TestTcpTreeOob:
+    #: fresh port blocks tried after the first one fails
+    RETRIES = 3
+
+    @classmethod
+    def _make_ends(cls, n):
+        """The n ranks' trees on a freshly probed port block. The probe
+        frees its port before the trees bind the ports above it, so
+        another process can take one of them in between: construction
+        then fails with OSError, and a new block is probed."""
+        for _ in range(1 + cls.RETRIES):
+            s = socket.socket()
+            s.bind(("127.0.0.1", 0))
+            base = s.getsockname()[1]
+            s.close()
+            ends = [None] * n
+            errs = []
+
+            def mk(r):
+                try:
+                    ends[r] = TcpTreeOob(r, n, base_port=base + 1, key="t",
+                                         ppn=2, radix=2, timeout_s=20)
+                except OSError as e:
+                    errs.append(e)
+
+            _run_threads(n, mk)
+            if not errs:
+                return ends
+            for e in ends:
+                if e is not None:
+                    e.close()
+        raise errs[0]
+
     def test_allgather_over_sockets(self):
-        s = socket.socket()
-        s.bind(("127.0.0.1", 0))
-        base = s.getsockname()[1]
-        s.close()
         n = 8
         assert TcpTreeOob.ports_needed(n, ppn=2, radix=2) == 7
-        ends = [None] * n
-
-        def mk(r):
-            ends[r] = TcpTreeOob(r, n, base_port=base + 1, key="t",
-                                 ppn=2, radix=2, timeout_s=20)
-
-        _run_threads(n, mk)
+        ends = self._make_ends(n)
         out = [None] * n
 
         def ag(r):

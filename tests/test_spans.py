@@ -143,7 +143,10 @@ def test_spans_count_and_nest_under_a_profile(job1, job4, tmp_path):
         assert _inside(child, ev["ucc.init"])
 
 
-def test_persistent_repost_skips_staging(job4, tmp_path):
+def test_persistent_repost_stages_in_place(job4, tmp_path):
+    """A persistent re-post runs the one launch path: it stages the
+    shards (already on their devices, so none is placed) and reuses the
+    compiled program (no build)."""
     job, teams = job4
     n = len(teams)
     argses = [CollArgs(
@@ -165,13 +168,15 @@ def test_persistent_repost_skips_staging(job4, tmp_path):
             assert rq.test() == Status.OK
             np.testing.assert_allclose(np.asarray(a.dst.buffer), 10.0)
 
-    round_()                       # first post stages and fills the cache
+    round_()                       # first post builds the program
     with _Capture(tmp_path):
         round_()
     tot = profiling.totals()
     assert tot["ucc.post"][0] == n
-    assert tot["ucc.xla.launch"][0] == tot["ucc.xla.dispatch"][0] == 1
-    assert "ucc.xla.stage" not in tot
+    assert tot["ucc.xla.launch"][0] == tot["ucc.xla.dispatch"][0] == \
+        tot["ucc.xla.stage"][0] == 1
+    assert "ucc.xla.place" not in tot
+    assert "ucc.xla.build" not in tot
     for rq in reqs:
         rq.finalize()
 

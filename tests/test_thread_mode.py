@@ -148,11 +148,10 @@ class TestThreadModeStress:
 
 
 class TestThreadModeFastLane:
-    """MULTIPLE-mode stress of the round-3 persistent FAST RE-POST lane
-    on device buffers: every rank re-posts from its own OS thread, the
-    last depositor's thread launches and finishes peers in set_result
-    (cross-thread super_status writes) — the exact interleaving the
-    lane's no-owner-completion argument must survive."""
+    """MULTIPLE-mode stress of persistent re-posts on device buffers:
+    every rank re-posts from its own OS thread, and the last depositor's
+    thread launches and sets every local task's result (cross-thread
+    set_result), while each owner completes its own task."""
 
     def test_concurrent_persistent_device_reposts(self):
         jax = pytest.importorskip("jax")

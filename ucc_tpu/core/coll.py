@@ -141,11 +141,6 @@ class CollRequest:
         # config read is a table lookup — both fixed after init
         self._persistent = args.is_persistent
         self._trace = bool(team.context.lib.config.coll_trace)
-        # persistent fast re-post lane (TL opt-in, e.g. XlaCollTask):
-        # eligibility probed once on the first re-post, after the first
-        # full post has warmed the TL's launch/program caches
-        self._fast = None if (self._persistent and not self._trace and
-                              hasattr(task, "fast_repost")) else False
 
     @property
     def status(self) -> Status:
@@ -171,7 +166,7 @@ class CollRequest:
 
     def post(self) -> Status:
         """ucc_collective_post (ucc_coll.c:375), inside the ``ucc.post``
-        layer span on every lane (plain, fast re-post, tuner, coalesce)."""
+        layer span on every lane (plain, tuner, coalesce)."""
         tok = profiling.begin("ucc.post")
         if tok is None:
             return self._post()
@@ -191,27 +186,6 @@ class CollRequest:
             if not self._persistent:
                 raise UccError(Status.ERR_INVALID_PARAM,
                                "re-post of non-persistent collective")
-            if self._fast or (self._fast is None and st == Status.OK and
-                              self._probe_fast()):
-                # the probe caches STRUCTURAL eligibility (coll shape,
-                # memtype, eager completion); observers can be attached
-                # between posts (EE triggered_post installs task.cb,
-                # schedules subscribe events) and must divert this round
-                # to the generic path, which runs them
-                task = self.task
-                if task.cb is None and task.triggered_task is None and \
-                        task.schedule is None and not task.timeout and \
-                        not any(task.em.listeners):
-                    if metrics.ENABLED:
-                        metrics.inc("coll_posted", component="core",
-                                    coll=task.coll_name or "",
-                                    alg=task.alg_name or "")
-                        metrics.inc("coll_fast_repost", component="core",
-                                    coll=task.coll_name or "",
-                                    alg=task.alg_name or "")
-                    if self._flight is not None:
-                        self._flight_post(task)
-                    return task.fast_repost()
             self.task.reset()
         self._posted = True
         self.task.progress_queue = self.team.context.progress_queue
@@ -244,13 +218,6 @@ class CollRequest:
         self._flight.post(team.id, team.epoch, fs, task.seq_num,
                           task.coll_name or "", task.alg_name or "",
                           self._flight_msgsize)
-
-    def _probe_fast(self) -> bool:
-        try:
-            self._fast = bool(self.task.fast_repost_ok())
-        except Exception:  # noqa: BLE001 - opt-in probe must never break post
-            self._fast = False
-        return self._fast
 
     # ------------------------------------------------------------------
     # autotuner probe lane (UCC_TUNER=online; score/tuner.py)
@@ -432,8 +399,7 @@ class CollRequest:
             # observers (user callback, EVENT subscribers, EE triggered
             # proxies) already saw the first attempt's error completion —
             # swapping in a fallback now would double-signal one
-            # collective (error then success). Same divert rule as the
-            # persistent fast re-post lane.
+            # collective (error then success).
             return False
         init_args, remaining = fb
         for cand in remaining:

@@ -209,8 +209,8 @@ GLOBAL_CONFIG = register_table(ConfigTable(prefix="", name="global", fields=[
                 "empty = ~/.cache/ucc_tpu/programs.pkl, 0/n = disable "
                 "(env-resolved)", parse_string),
     ConfigField("GEN_COST_CACHE", "", "fitted alpha-beta cost-model "
-                "file (JSON, written by `ucc_tune --gen-search` / the "
-                "search gate smoke; read by `ucc_perftest --sweep` for "
+                "file (JSON, written by `ucc_tune --gen-search`; read "
+                "by `ucc_perftest --sweep` for "
                 "the predicted_us column); empty = "
                 "~/.cache/ucc_tpu/cost.json (env-resolved)",
                 parse_string),

@@ -173,8 +173,8 @@ class Team:
                 metrics.observe("team_state_dwell_us", dwell * 1e6,
                                 component="core/team", coll=old.name)
             # bootstrap span: each left state becomes a completed stage
-            # event on the flight ring, so a slow team create (the
-            # BENCH_r14 324s wall) is attributable per state — oob
+            # event on the flight ring, so a slow team create is
+            # attributable per state — oob
             # rounds, service-team build, TUNER_SYNC — in `ucc_fr`
             # output instead of reading as one opaque gap
             fr = getattr(self.context, "flight", None)

@@ -814,7 +814,7 @@ def parse_device_families(spec: str) -> Dict[str, List[int]]:
 def device_programs(n: int, quant_mode: str = "",
                     spec: str = "") -> List[Program]:
     """Every verified AND device-lowerable built-in program at team
-    size *n* (the gate smoke's compile+verify sweep)."""
+    size *n*."""
     from .registry import build_program
     out: List[Program] = []
     seen: set = set()
@@ -940,13 +940,9 @@ def _make_task_class():
             count = self.src_count()
             # the gen param string is part of the cache key: generated
             # variants must never collide with each other or with the
-            # monolithic lax programs (ISSUE 15 tentpole). Entries
-            # deliberately ride the UNBOUNDED shared.programs dict (not
-            # _cache_insert): aot_programs is keyed by id(program) and
-            # that key is only valid because programs pins the jit
-            # objects for the team's lifetime — evicting here could
-            # alias a recycled id onto a stale AOT executable. The
-            # whole dict is dropped at team destroy (shared.put)
+            # monolithic lax programs. Entries ride the team's
+            # shared.programs dict, which is dropped at team destroy
+            # (shared.put)
             key = ("gen_dev", self.prog.name, self.prog.param_str,
                    self._backend, self.coll, op, self.np_dtype.str,
                    count, self._dev_root,

@@ -412,10 +412,9 @@ def built_in_programs(n: int,
                       quant_mode: str = "",
                       spec: str = "",
                       paths=None) -> List[Program]:
-    """Every verified built-in program at team size *n* (the gate
-    smoke's compile+verify sweep). ``quant_mode`` enables the fused
-    quantized program (and the quantized-DCN hier variants when
-    *paths* describe a multi-node topology)."""
+    """Every verified built-in program at team size *n*. ``quant_mode``
+    enables the fused quantized program (and the quantized-DCN hier
+    variants when *paths* describe a multi-node topology)."""
     out: List[Program] = []
     names: set = set()
 

@@ -266,7 +266,7 @@ def interleaved_measure(teams, contexts, argses, coll: CollType, mem,
     """Time score-map candidates *idxs* with INTERLEAVED iterations:
     iteration i runs every candidate once before iteration i+1 runs
     any, so clock drift and background noise hit all candidates
-    equally (the interleaved-median methodology of BENCH_r14). Returns
+    equally. Returns
     {idx: median_us or None-for-failed}."""
     from ..score.tuner import forced_request
     from ..status import Status, UccError
@@ -946,9 +946,9 @@ def run_device_search(n: int, colls: Sequence[str],
 
 
 # ---------------------------------------------------------------------------
-# BENCH driver (python -m ucc_tpu.dsl.search --bench): the >=128-rank
-# acceptance run — searched vs EVERY fixed grid point, interleaved
-# medians, predicted-vs-measured for every finalist -> BENCH_r14.json
+# bench driver (python -m ucc_tpu.dsl.search --bench): the >=128-rank
+# run — searched vs EVERY fixed grid point, interleaved medians,
+# predicted-vs-measured for every finalist, as one JSON record
 # ---------------------------------------------------------------------------
 
 def synthetic_paths(n: int) -> Optional[List[tuple]]:
@@ -1200,7 +1200,7 @@ def main(argv: Optional[List[str]] = None) -> int:
         description="cost-model-guided program search — bench driver")
     p.add_argument("--bench", action="store_true",
                    help="searched-vs-grid acceptance bench on a "
-                        "simulated mesh (BENCH_r14 methodology)")
+                        "simulated mesh (interleaved medians)")
     p.add_argument("-n", "--nprocs", type=int, default=128)
     p.add_argument("--sizes", default="16K,256K,2M")
     p.add_argument("-i", "--iters", type=int, default=5)

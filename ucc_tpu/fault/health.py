@@ -33,9 +33,8 @@ On detection the registry cancels every in-flight task whose team
 contains a failed rank with ``Status.ERR_RANK_FAILED`` (stamping
 ``task.failed_ranks`` for attribution), bumps the
 ``rank_failures_detected`` metric, and — when the watchdog is armed —
-appends a ``rank_failed`` evidence line to the watchdog file so
-``tools/snapshot_gate.py`` classifies the run
-``rank_failed(ranks=...)`` instead of ``hang``.
+appends a ``rank_failed`` evidence line to the watchdog file, so a
+reader of that file can tell ``rank_failed(ranks=...)`` from ``hang``.
 """
 from __future__ import annotations
 

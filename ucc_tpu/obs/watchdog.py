@@ -101,9 +101,8 @@ def register_team(team: Any) -> None:
 def note_rank_failure(ranks, source: str = "", detail: str = "") -> None:
     """Append a ``rank_failed`` evidence line to the watchdog file
     (called by fault/health on detection). Only when the watchdog is
-    armed — tools/snapshot_gate.py always arms it, and parses this line
-    to classify a run ``rank_failed(ranks=...)`` instead of
-    ``hang``/``timeout``."""
+    armed; the line lets a reader of the file classify a run
+    ``rank_failed(ranks=...)`` instead of ``hang``/``timeout``."""
     if not ENABLED:
         return
     rec = {"ts": time.time(), "pid": os.getpid(), "reason": "rank_failed",

@@ -1,13 +1,12 @@
 """Shared quantization verification/reporting helpers.
 
-One home for the ``detail.quant`` record shape so its producers
-(``ucc_perftest --quant``, ``bench.py --quant``) and its consumer
-(``tools/snapshot_gate.py`` quant smoke) cannot drift: the static wire
+One home for the ``detail.quant`` record shape (``ucc_perftest
+--quant``) and the code that fills it: the static wire
 accounting, the random-data error stats, and a measured-bytes probe
 that temporarily flips the metrics registry on around a verification
 round and reads the ``bytes_sent`` delta — actual transport traffic,
-not the formula the static fields come from, which is what makes the
-gate's "beats exact on wire bytes" check falsifiable.
+not the formula the static fields come from, which is what makes a
+"beats exact on wire bytes" check falsifiable.
 """
 from __future__ import annotations
 

@@ -369,8 +369,8 @@ class InProcTransport:
             # completions through a mapped publication window (no ffi on
             # the poll path), so it is the default in BOTH thread modes,
             # including under UCC_FT=shrink. GIL-released matching still
-            # wins big when many OS threads drive progress concurrently
-            # (tools/native_bench.py). UCC_TL_SHM_NATIVE overrides in
+            # wins big when many OS threads drive progress
+            # concurrently. UCC_TL_SHM_NATIVE overrides in
             # either direction.
             env = os.environ.get("UCC_TL_SHM_NATIVE", "").strip().lower()
             if env and env != "auto":   # auto = same as unset
@@ -399,8 +399,7 @@ class InProcTransport:
                 get_logger("tl_shm").warning(
                     "native matcher requested but unavailable (no source "
                     "checkout / build failed, see native/build.log) — "
-                    "falling back to the python matcher "
-                    "(tools/native_bench.py quantifies the cost)")
+                    "falling back to the python matcher")
         with _SHM_LOCK:
             _SHM_WORLD[self.uid] = self
 

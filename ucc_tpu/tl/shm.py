@@ -50,8 +50,8 @@ class TlShmContext(BaseContext):
         # completion window instead of per-poll ffi) is the default in
         # BOTH thread modes — single-threaded it holds parity with the
         # in-GIL python matcher and GIL-released matching wins big under
-        # concurrent progress threads (tools/native_bench.py). The
-        # UCC_TL_SHM_NATIVE knob (env or config file) overrides.
+        # concurrent progress threads. The UCC_TL_SHM_NATIVE knob (env or
+        # config file) overrides.
         use_native = None
         if config is not None:
             try:

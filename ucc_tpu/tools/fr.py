@@ -14,14 +14,12 @@ obs/diagnose.py):
                                              # ring appended to its
                                              # UCC_FLIGHT_FILE)
     ucc_fr --smoke                           # self-contained diagnosis
-                                             # drill (snapshot_gate's
-                                             # UCC_GATE_FR probe)
+                                             # drill
     ucc_fr --feedback-smoke                  # closed-loop drill: the
                                              # continuous collector flags
                                              # a pinned straggler and
                                              # selection moves off the
-                                             # through-it ring (the
-                                             # UCC_GATE_FEEDBACK probe)
+                                             # through-it ring
 
 Input files hold one JSON record per line — ``flight_local`` (one
 rank's ring, written on SIGUSR2 or by embedders) and/or
@@ -105,7 +103,7 @@ def print_report(merged: Dict[str, Any], diag: Dict[str, Any],
 
 def _smoke(args) -> int:
     """Self-contained diagnosis drill (see module doc). Prints one JSON
-    record the gate parses:
+    record:
     ``{"metric": "fr_smoke", "pinned_rank": R, "culprit_ranks": [...],
     "stuck_seqs": [...], "ok": bool}``."""
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
@@ -162,7 +160,7 @@ def _smoke(args) -> int:
         rec["summary"] = diag.get("summary", [])[:6]
         rec["ok"] = rec["culprit_ranks"] == [args.smoke_rank] and \
             bool(rec["stuck_seqs"])
-    except Exception as e:  # noqa: BLE001 - the gate reports, not raises
+    except Exception as e:  # noqa: BLE001 - the drill reports, not raises
         rec["error"] = f"{type(e).__name__}: {e}"
         rec["ok"] = False
     print(json.dumps(rec))
@@ -178,7 +176,7 @@ def _feedback_smoke(args) -> int:
     the RankBias. Passes when the collector flags a rank without any
     manual dump trigger within the window budget, selection demonstrably
     moves off the ring, and post-feedback p99 beats pre-feedback.
-    Prints one JSON record the gate parses:
+    Prints one JSON record:
     ``{"metric": "feedback_smoke", "pinned_rank": R, "flagged": [...],
     "windows_to_flag": W, "pre_alg": "...", "post_alg": "...",
     "pre_p99_ms": ..., "post_p99_ms": ..., "ok": bool}``."""
@@ -279,7 +277,7 @@ def _feedback_smoke(args) -> int:
             rec["windows_to_flag"] <= 2 and \
             pre_alg == "ring" and post_alg != "ring" and \
             rec["post_p99_ms"] < rec["pre_p99_ms"]
-    except Exception as e:  # noqa: BLE001 - the gate reports, not raises
+    except Exception as e:  # noqa: BLE001 - the drill reports, not raises
         rec["error"] = f"{type(e).__name__}: {e}"
         rec["ok"] = False
     print(json.dumps(rec))

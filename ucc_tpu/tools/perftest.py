@@ -428,7 +428,7 @@ def _quant_verify(job, coll, n, count, dt, mem, devices, budget, seed=5):
 def _quant_detail(job, coll, n, count, dt, mem, devices, bw):
     """The ``detail.quant`` record: effective (wire) vs logical busbw
     plus the measured error and measured wire bytes of one random-data
-    round (record shape shared with bench.py via quant.verify)."""
+    round (record shape from quant.verify)."""
     from ucc_tpu import quant as _q
     from ucc_tpu.quant.verify import base_detail
     params = _q.params_for(job.teams[0] if hasattr(job, "teams")

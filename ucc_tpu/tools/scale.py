@@ -11,7 +11,7 @@ sync — at sizes the flat bootstrap cannot reach), runs the collective
 matrix, and measures the N-level hier allreduce against the best flat
 candidate on a size grid.
 
-CLI (one JSON record on stdout, the ``UCC_GATE_SCALE`` smoke's input)::
+CLI (one JSON record on stdout)::
 
     python -m ucc_tpu.tools.scale -n 512 --ppn 8 --npp 8 --json
 """
@@ -69,7 +69,7 @@ def _restore_env(old: Dict[str, Optional[str]]) -> None:
 
 def _oob_stats(endpoints) -> dict:
     """Aggregate bootstrap-tree metrics across a world's endpoints: the
-    O(log n) evidence the gate smoke asserts."""
+    O(log n) evidence."""
     levels = max(e.stats["levels"] for e in endpoints)
     fanin = max(e.stats["max_fanin"] for e in endpoints)
     rounds_per_ag = 0.0
@@ -323,7 +323,7 @@ class ScaleSim:
         rep = {"ctx": _oob_stats(self.ctx_eps),
                "team": _oob_stats(self.team_eps),
                "flat_equiv_fanin": self.n}
-        # the logarithmic claim, precomputed for the gate: rounds per
+        # the logarithmic claim, precomputed for the reader: rounds per
         # allgather bounded by 2*levels and fan-in by max(ppn, radix)
         rep["log2_n"] = round(math.log2(max(2, self.n)), 2)
         return rep

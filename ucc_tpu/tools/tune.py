@@ -19,10 +19,6 @@ Examples::
     # compile a cache from a perftest sweep instead of measuring here
     python -m ucc_tpu.tools.perftest -c allreduce --sweep > sweep.jsonl
     python -m ucc_tpu.tools.tune --from sweep.jsonl -p 4
-
-    # warn-only CI probe (tools/snapshot_gate.py): sweep one point,
-    # round-trip it through the cache, report tuned-vs-default
-    python -m ucc_tpu.tools.tune --gate-smoke
 """
 from __future__ import annotations
 
@@ -30,17 +26,12 @@ import argparse
 import json
 import os
 import sys
-import tempfile
-import time
 from typing import List, Optional
 
-import numpy as np
-
 import ucc_tpu
-from ucc_tpu import Status
 from ucc_tpu.api.types import coll_args_msgsize
 from ucc_tpu.constants import (CollType, DataType, MemoryType, ReductionOp,
-                               coll_type_str, dt_size)
+                               dt_size)
 from ucc_tpu.score.tuner import (cand_label, compile_measurements,
                                  measure_candidate, measurement_record,
                                  resolve_cache_path, store_entries,
@@ -53,32 +44,12 @@ from .perftest import COLLS, InProcJob, lat_stats, make_args
 class _Job(InProcJob):
     """perftest's in-process job with lib config overrides — the sweep
     itself always runs with the tuner OFF so measurements see the
-    untouched static map — plus a bounded wait for full-dispatch
-    measurement loops."""
+    untouched static map."""
 
     def __init__(self, n: int, overrides: Optional[dict] = None,
                  create_timeout: float = 120.0):
         super().__init__(n, lib_overrides=overrides,
                          create_timeout=create_timeout)
-
-    def wait(self, reqs, timeout: float = 120.0) -> bool:
-        deadline = time.monotonic() + timeout
-        while any(rq.test() == Status.IN_PROGRESS for rq in reqs):
-            for c in self.contexts:
-                c.progress()
-            if time.monotonic() > deadline:
-                for rq in reqs:
-                    rq.task.cancel(Status.ERR_TIMED_OUT)
-                return False
-        return all(rq.test() == Status.OK for rq in reqs)
-
-
-def _finalize_all(reqs) -> None:
-    for rq in reqs:
-        try:
-            rq.finalize()
-        except Exception:  # noqa: BLE001 - sweep cleanup is best-effort
-            pass
 
 
 def run_sweep(job: _Job, colls: List[str], sizes: List[int], iters: int,
@@ -158,70 +129,6 @@ def _summary(job: _Job, records: List[dict], entries: List[dict]) -> None:
     print(f"# compiled {len(entries)} cache entries")
 
 
-def _measure_default(job: _Job, size: int, iters: int, warmup: int) -> float:
-    """Time the allreduce the score map actually selects (full dispatch,
-    persistent) — the tuned-vs-default probe of --gate-smoke."""
-    n = job.n
-    count = max(1, size // 4)
-    argses = [make_args(CollType.ALLREDUCE, r, n, count, DataType.FLOAT32,
-                        ReductionOp.SUM, MemoryType.HOST, False, 0, True,
-                        None) for r in range(n)]
-    reqs = [job.teams[r].collective_init(argses[r]) for r in range(n)]
-    lats = []
-    for it in range(warmup + iters):
-        t0 = time.perf_counter()
-        for rq in reqs:
-            rq.post()
-        if not job.wait(reqs):
-            _finalize_all(reqs)
-            return float("inf")
-        if it >= warmup:
-            lats.append(time.perf_counter() - t0)
-    _finalize_all(reqs)
-    return lat_stats(lats)["p50_us"]
-
-
-def run_gate_smoke(iters: int = 10) -> int:
-    """Warn-only CI probe (tools/snapshot_gate.py): sweep the bench.py
-    allreduce shape on one point, write a throwaway cache, reload it in
-    a second job with UCC_TUNER=offline, and report tuned vs default
-    latency plus whether the learned selection actually engaged. Always
-    exits 0 — the gate only records the delta."""
-    size = 64 << 10
-    cache = os.path.join(tempfile.mkdtemp(prefix="ucc_tune_gate_"),
-                         "tune.json")
-    job = _Job(4, {"TUNER": "off"})
-    try:
-        records = run_sweep(job, ["allreduce"], [size], iters, 3,
-                            verbose=False)
-        sig = topo_signature(job.teams[0])
-        entries = compile_measurements(records)
-        default_us = _measure_default(job, size, iters, 3)
-    finally:
-        job.destroy()
-    if not records or not entries:
-        print(json.dumps({"metric": "tuner_gate_smoke",
-                          "error": "sweep produced no measurements"}))
-        return 0
-    store_entries(cache, sig, entries, source="offline")
-    job2 = _Job(4, {"TUNER": "offline", "TUNER_CACHE": cache})
-    try:
-        cands = sweep_candidates(job2.teams[0], CollType.ALLREDUCE,
-                                 MemoryType.HOST, size)
-        learned = bool(cands) and cands[0].origin == "learned"
-        winner = "/".join(cand_label(cands[0])) if cands else "?"
-        tuned_us = _measure_default(job2, size, iters, 3)
-    finally:
-        job2.destroy()
-    rec = {"metric": "tuner_gate_smoke", "size_bytes": size,
-           "default_us": round(default_us, 2),
-           "tuned_us": round(tuned_us, 2), "winner": winner,
-           "learned_selection": learned,
-           "ratio": round(tuned_us / default_us, 4) if default_us else 0.0}
-    print(json.dumps(rec), flush=True)
-    return 0
-
-
 def main(argv=None) -> int:
     p = argparse.ArgumentParser(
         prog="ucc_tune",
@@ -251,10 +158,6 @@ def main(argv=None) -> int:
                         "a live -p team for it)")
     p.add_argument("--dry-run", action="store_true",
                    help="print the compiled entries, write nothing")
-    p.add_argument("--gate-smoke", action="store_true",
-                   help="warn-only CI probe: one-point sweep + cache "
-                        "round-trip, prints a tuned-vs-default JSON "
-                        "record, always exits 0")
     p.add_argument("--quant", nargs="?", const="env", default="",
                    choices=["env", "int8", "fp8"],
                    help="include quantized candidates in the sweep: sets "
@@ -314,9 +217,6 @@ def main(argv=None) -> int:
 
     from ucc_tpu.utils.backend import setup_backend
     setup_backend(virtual_cpu_devices=max(args.nprocs, 4))
-
-    if args.gate_smoke:
-        return run_gate_smoke(args.iters if args.iters != 20 else 10)
 
     cache_path = resolve_cache_path(
         args.output or os.environ.get("UCC_TUNER_CACHE", ""))
