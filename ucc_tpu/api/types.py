@@ -19,8 +19,10 @@ from typing import Any, Callable, List, Optional, Sequence, Union
 
 import numpy as np
 
-from ..constants import (CollArgsFlags, CollSyncType, CollType, DataType,
-                         GenericDataType, MemoryType, ReductionOp, ThreadMode)
+from ..constants import (FLAG_IN_PLACE, FLAG_PERSISTENT, ROOTED_MASK,
+                         CollArgsFlags, CollSyncType, CollType, DataType,
+                         GenericDataType, MemoryType, ReductionOp, ThreadMode,
+                         dt_size)
 from ..status import Status
 from ..utils.ep_map import EpMap
 
@@ -209,26 +211,24 @@ class CollArgs:
     dst_memh: Any = None
 
     # -- convenience predicates ------------------------------------------
+    # int masks: an ``&`` on the IntFlag itself runs enum.Flag.__and__
     @property
     def is_inplace(self) -> bool:
-        return bool(self.flags & CollArgsFlags.IN_PLACE)
+        return bool(int(self.flags) & FLAG_IN_PLACE)
 
     @property
     def is_persistent(self) -> bool:
-        return bool(self.flags & CollArgsFlags.PERSISTENT)
+        return bool(int(self.flags) & FLAG_PERSISTENT)
 
     @property
     def is_rooted(self) -> bool:
-        from ..constants import ROOTED_COLLS
-        return bool(self.coll_type & ROOTED_COLLS)
+        return bool(int(self.coll_type) & ROOTED_MASK)
 
 
 def coll_args_msgsize(args: CollArgs, team_size: int, rank: int = 0) -> int:
     """ucc_coll_args_msgsize (ucc_coll_utils.h:209): bytes that drive
     score-range selection. Vector colls sum their counts; rooted colls use
     the root-relevant size."""
-    from ..constants import dt_size
-
     ct = args.coll_type
     if ct == CollType.BARRIER or ct == CollType.FANIN or ct == CollType.FANOUT:
         return 0

@@ -12,11 +12,13 @@ import time
 from dataclasses import dataclass
 
 import numpy as np
-from typing import Any, Optional
+from typing import TYPE_CHECKING, Any, Optional
 
 from ..api.types import (BufferInfo, BufferInfoV, CollArgs,
                          coll_args_msgsize)
-from ..constants import (CollArgsFlags, CollType, MemoryType, coll_type_str)
+from ..constants import (FLAG_MEM_MAPPED_BUFFERS, FLAG_TIMEOUT, CollType,
+                         DataType, EventType, GenericDataType, MemoryType,
+                         ReductionOp, coll_type_str)
 from .. import integrity
 from ..mc.base import detect_mem_type
 from ..obs import metrics
@@ -25,9 +27,19 @@ from ..schedule.task import CollTask
 from ..status import RankFailedError, Status, UccError
 from ..utils import profiling
 from ..utils.log import get_logger
-from .team import Team
+
+if TYPE_CHECKING:
+    # team.py imports this module for Team.collective_init
+    from .team import Team
 
 logger = get_logger("coll")
+
+#: the collectives that get a dt-validation prefix (``_maybe_wrap_dt_check``)
+#: and those whose results are attested, as int masks: an ``&`` on the
+#: IntFlag itself runs enum.Flag.__and__ in Python on every request
+_DT_CHECKED = int(CollType.GATHER | CollType.GATHERV | CollType.SCATTER
+                  | CollType.SCATTERV | CollType.BCAST | CollType.REDUCE)
+_ATTESTED = int(integrity.ATTEST_COLLS)
 
 
 class _DtCheckTask(CollTask):
@@ -44,7 +56,6 @@ class _DtCheckTask(CollTask):
         self._svc = None
 
     def post_fn(self) -> Status:
-        from ..constants import ReductionOp
         self._svc = self.core_team.service_team.service_allreduce(
             self.vec, ReductionOp.MIN)
         self._svc.post()
@@ -137,10 +148,9 @@ class CollRequest:
         #: by collective_init for plain (unwrapped, non-persistent) tasks
         self._fallback = None
         self._fb_used = False
-        # hot-path caches: flag tests are enum __and__ calls and the
-        # config read is a table lookup — both fixed after init
+        # fixed after init: read once here, not on every post
         self._persistent = args.is_persistent
-        self._trace = bool(team.context.lib.config.coll_trace)
+        self._trace = team.coll_trace
 
     @property
     def status(self) -> Status:
@@ -374,7 +384,6 @@ class CollRequest:
             # but this request stays IN_PROGRESS until every live rank's
             # result digest has been exchanged and compared (raises
             # DataCorruptedError on a digest minority)
-            from .. import integrity
             return integrity.attest_test(self)
         return st
 
@@ -534,7 +543,7 @@ def _init_request(args: CollArgs, team: Team) -> CollRequest:
     onesided_args = (args.global_work_buffer is not None
                      or args.src_memh is not None
                      or args.dst_memh is not None
-                     or bool(args.flags & CollArgsFlags.MEM_MAPPED_BUFFERS))
+                     or bool(int(args.flags) & FLAG_MEM_MAPPED_BUFFERS))
     if onesided_args and mem_type == MemoryType.TPU:
         # one-sided args on HOST memory are served by the socket/shm
         # RDMA-emulation path (tl/host/onesided.py, TUNE-selected like the
@@ -591,7 +600,7 @@ def _init_request(args: CollArgs, team: Team) -> CollRequest:
     # on cold paths
     task.coll_name = coll_type_str(ct)
     task.alg_name = str(chosen.alg_name or chosen.team)
-    if team.context.lib.config.coll_trace:
+    if team.coll_trace:
         logger.info("coll init: %s/%s msgsize %d -> %s (score %d) team %s",
                     coll_type_str(ct), mem_type.name.lower(), msgsize,
                     chosen.alg_name or chosen.team, chosen.score, team.id)
@@ -635,7 +644,7 @@ def _init_request(args: CollArgs, team: Team) -> CollRequest:
         # at rank-local times, which would skew wire-tag parity for a
         # held member).
         req._coalesce = coal
-    elif task is inner and not args.is_persistent:
+    elif task is inner and not req._persistent:
         # retain the fallback-chain tail for RUNTIME fallback (see
         # CollRequest._try_runtime_fallback). Wrapped (dt-check) and
         # persistent tasks are excluded: the former's failure status is
@@ -653,7 +662,7 @@ def _init_request(args: CollArgs, team: Team) -> CollRequest:
         coal.flush("ineligible")
     if integrity.VERIFY and task is inner and team.size > 1 and \
             args.active_set is None and mem_type == MemoryType.HOST and \
-            (ct & integrity.ATTEST_COLLS) and req._coalesce is None and \
+            (int(ct) & _ATTESTED) and req._coalesce is None and \
             req._tuner is None:
         # sampled cross-rank result attestation (UCC_INTEGRITY=verify):
         # binds _attest at the deterministic UCC_INTEGRITY_SAMPLE cadence.
@@ -670,19 +679,16 @@ def _maybe_wrap_dt_check(task: CollTask, args: CollArgs, team: Team,
                          mem_type: MemoryType) -> CollTask:
     """Rooted colls optionally get a dt-validation schedule prefix
     (ucc_coll.c:274-289)."""
-    from ..constants import DataType, EventType, GenericDataType
     # the reference scopes this to the gather/scatter family
     # (ucc_coll.c:274-277); we additionally wrap bcast/reduce — the same
     # root-vs-leaf dt asymmetry can corrupt them. Note the zero-size fast
     # path means a rank posting all-zero counts skips the check (same
     # property as ucc_coll.c:191 vs :274). Active-set colls are excluded:
     # only the subset posts, but the validation allreduce is team-wide.
-    checked = (CollType.GATHER | CollType.GATHERV | CollType.SCATTER
-               | CollType.SCATTERV | CollType.BCAST | CollType.REDUCE)
-    if not (args.coll_type & checked) or team.size <= 1 or \
+    if not (int(args.coll_type) & _DT_CHECKED) or team.size <= 1 or \
             args.active_set is not None:
         return task
-    if not team.context.lib.config.check_asymmetric_dt:
+    if not team.check_asymmetric_dt:
         return task
     if team.service_team is None or \
             not hasattr(team.service_team, "service_allreduce"):
@@ -716,7 +722,7 @@ def _attach_profiling(task: CollTask, ct: CollType) -> None:
 
 
 def _attach_user_opts(task: CollTask, args: CollArgs) -> None:
-    if args.flags & CollArgsFlags.TIMEOUT and args.timeout > 0:
+    if int(args.flags) & FLAG_TIMEOUT and args.timeout > 0:
         task.timeout = args.timeout
     if args.cb is not None:
         task.cb = args.cb

@@ -37,6 +37,7 @@ from ..status import Status, UccError
 from ..topo.topo import TeamTopo
 from ..utils.ep_map import EpMap
 from ..utils.log import get_logger
+from .coll import collective_init as _collective_init
 from .context import Context
 
 logger = get_logger("core")
@@ -139,6 +140,11 @@ class Team:
         if pr is None:
             pr = os.environ.get("UCC_TEAM_PRIORITY", DEFAULT_PRIORITY)
         self.priority = clamp_priority(pr)
+        # library settings every request reads, fixed once the lib exists:
+        # read here, not through Config.__getattr__ on each collective_init
+        cfg = context.lib.config
+        self.coll_trace = bool(cfg.coll_trace)
+        self.check_asymmetric_dt = bool(cfg.check_asymmetric_dt)
         # the watchdog enumerates live teams so a create-time hang names
         # its state-machine position (WeakSet; no lifetime extension)
         watchdog.register_team(self)
@@ -316,7 +322,7 @@ class Team:
                 logger.exception("tuner activation failed; team %s "
                                  "continues with the static score map",
                                  self.id)
-            if self.context.lib.config.coll_trace:
+            if self.coll_trace:
                 # dumped here, not in _build_score_map, so learned rows
                 # show with their (learned) provenance
                 logger.info("%s", self.score_map.print_info(
@@ -541,8 +547,7 @@ class Team:
         return self.seq_num
 
     def collective_init(self, args):
-        from .coll import collective_init
-        return collective_init(args, self)
+        return _collective_init(args, self)
 
     def destroy(self) -> Status:
         """Release the team's component teams. Must be safe on a

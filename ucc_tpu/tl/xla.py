@@ -33,9 +33,10 @@ from typing import Any, Dict, List, NamedTuple, Optional, Tuple
 import numpy as np
 
 from ..api.types import BufferInfo, BufferInfoV
-from ..constants import (COLL_TYPE_ALL, CollType, MemoryType, ReductionOp,
-                         coll_type_str, dt_numpy)
+from ..constants import (COLL_TYPE_ALL, CollType, GenericDataType,
+                         MemoryType, ReductionOp, coll_type_str, dt_numpy)
 from ..core.components import BaseContext, BaseLib, TransportLayer, register_tl
+from ..obs import flight as _flight_mod
 from ..schedule.task import CollTask
 from ..score.score import CollScore
 from ..status import Status, UccError
@@ -582,7 +583,6 @@ class XlaCollTask(CollTask):
             raise UccError(Status.ERR_NOT_SUPPORTED,
                            "tl/xla does not run active-set collectives "
                            "(subset posting vs full-team rendezvous)")
-        from ..constants import GenericDataType
         if isinstance((args.src or args.dst).datatype, GenericDataType):
             # compiled programs need a numeric compute type; the host TLs
             # move generic dts as raw bytes (reference device TLs reject
@@ -674,7 +674,6 @@ class XlaCollTask(CollTask):
         # so ucc_fr could not attribute device-side stragglers
         self._flight = None
         self._flight_nbytes = int(getattr(init_args, "msgsize", 0) or 0)
-        from ..obs import flight as _flight_mod
         if _flight_mod.ENABLED:
             self._flight = getattr(team.core_team.context, "flight",
                                    None)
