@@ -1,6 +1,7 @@
 """The harness finds every cell's files by name and refuses to run
 without the chip or without the program."""
 import json
+import math
 import shutil
 import subprocess
 import sys
@@ -12,14 +13,29 @@ from yardstick import spec, trace
 SPEC = json.loads((spec.ROOT / "BENCHMARK.json").read_text())
 
 
+def parallel_degree(deployment: dict) -> int:
+    """Chips a deployment spans: the product of its ``*_parallel``
+    degrees (``data_parallel``, ``expert_parallel``, ...)."""
+    degrees = [int(v) for k, v in deployment.items()
+               if k.endswith("_parallel")]
+    assert degrees, f"deployment names no parallel degree: {deployment}"
+    return math.prod(degrees)
+
+
 @pytest.mark.parametrize("name", [w["name"] for w in SPEC["workloads"]])
 def test_every_cell_resolves(name):
     cell = spec.load_cell(name)
-    assert cell.config["deployment"]["data_parallel"] == cell.chips
+    dep = cell.config["deployment"]
+    # a cell runs its deployment's whole degree, or, where the
+    # configuration says how (``cut_to_chips``), one chip's share of it
+    degree = parallel_degree(dep)
+    assert cell.chips == degree or (
+        "cut_to_chips" in dep and cell.chips < degree)
     assert {m["name"] for m in cell.per_layer} == set(cell.readers)
     assert any(m["name"] == "setup_s" for m in cell.end_to_end)
     assert len(cell.end_to_end) >= 2 and cell.per_layer
-    assert spec.parameters(cell.config)
+    if "architecture" in cell.config:   # a parameter list, as DDP's buckets
+        assert spec.parameters(cell.config)
 
 
 def test_bare_checkout_exits_nonzero_without_result(tmp_path):
