@@ -133,7 +133,7 @@ class TestXlaAllreduce:
 @pytest.fixture(scope="class")
 def program_job():
     """A 4-rank job with the short-message path off, so that every
-    allreduce runs the compiled ``xla`` program."""
+    collective runs the compiled ``xla`` program."""
     with pytest.MonkeyPatch.context() as mp:
         mp.setenv("UCC_TL_XLA_SHORT_MSG_MAX", "0")
         j = UccJob(4)
@@ -210,6 +210,98 @@ class TestXlaAllreduceProgram:
             out = np.asarray(argses[r].dst.buffer)
             assert out.shape == (count,)
             np.testing.assert_array_equal(out.astype(np.float64), expect)
+
+
+def _lowered(team, coll, op, dtype, count):
+    """StableHLO text of the team's compiled ``xla`` program of ``coll``
+    with ``op`` in ``dtype`` at per-rank launch count ``count``."""
+    xla_team = next(t for t in team.cl_teams[0].tl_teams if t.name == "xla")
+    shared = xla_team.shared
+    (program, padded), = [v for k, v in shared.programs.items()
+                          if k[:5] == (coll, op, np.dtype(dtype).str, count,
+                                       "xla")]
+    x = jax.ShapeDtypeStruct((len(shared.devices) * padded,), dtype)
+    return program.lower(x).as_text()
+
+
+class TestXlaGatherScatterProgram:
+    """The compiled allgather and reduce-scatter programs on the flat
+    shard, at a count that is a multiple of the team size and no multiple
+    of 128, in 16- and 32-bit floats, in place and not; a total that the
+    team size does not divide still takes the replicated fallback."""
+
+    @pytest.mark.parametrize("inplace", [False, True])
+    @pytest.mark.parametrize("dt,np_dt", [
+        (DataType.BFLOAT16, jnp.bfloat16), (DataType.FLOAT32, np.float32)])
+    @pytest.mark.parametrize("coll,op", [
+        (CollType.ALLGATHER, None),
+        (CollType.REDUCE_SCATTER, ReductionOp.SUM),
+        (CollType.REDUCE_SCATTER, ReductionOp.AVG)])
+    def test_matches_numpy(self, program_job, coll, op, dt, np_dt, inplace):
+        job, teams = program_job
+        n, count = 4, 1004
+        rng = np.random.default_rng(count + int(inplace) +
+                                    (int(op) if op is not None else 7))
+        gather = coll == CollType.ALLGATHER
+        total = n * count if gather else count
+        # small ints, a multiple of n: every sum and mean is exact
+        srcs = [(rng.integers(-16, 16, total if not gather or inplace
+                              else count) * n).astype(np_dt)
+                for _ in range(n)]
+        blk = count if gather else count // n
+        argses = []
+        for r in range(n):
+            if inplace:
+                argses.append(CollArgs(
+                    coll_type=coll, dst=tpu_buf(job, r, srcs[r], dt), op=op,
+                    flags=CollArgsFlags.IN_PLACE))
+            else:
+                argses.append(CollArgs(
+                    coll_type=coll, src=tpu_buf(job, r, srcs[r], dt),
+                    dst=BufferInfo(None, total if gather else blk, dt,
+                                   mem_type=MemoryType.TPU), op=op))
+        run_xla(job, teams, lambda r: argses[r])
+        s = np.stack(srcs).astype(np.float64)
+        if gather:
+            mine = [s[r, r * blk:(r + 1) * blk] if inplace else s[r]
+                    for r in range(n)]
+            expect = [np.concatenate(mine)] * n
+        else:
+            red = s.sum(0) / (n if op == ReductionOp.AVG else 1)
+            expect = [red[r * blk:(r + 1) * blk] for r in range(n)]
+        for r in range(n):
+            out = np.asarray(argses[r].dst.buffer)
+            assert out.dtype == np.dtype(np_dt)
+            np.testing.assert_array_equal(out.astype(np.float64), expect[r])
+        text = _lowered(teams[0], coll, op, np_dt, count)
+        name = "allgather" if gather else "reduce_scatter"
+        assert f"module @jit_ucc_{name}_xla" in text
+        assert f"stablehlo.{'all_gather' if gather else name}" in text
+        assert "stablehlo.reshape" not in text     # no [1, N] view
+
+    def test_reduce_scatter_remainder_falls_back(self, program_job):
+        from ucc_tpu.utils.mathutils import block_count, block_offset
+        job, teams = program_job
+        n, total = 4, 1003
+        srcs = [np.arange(total, dtype=np.float32) * (r + 1)
+                for r in range(n)]
+        argses = [CollArgs(
+            coll_type=CollType.REDUCE_SCATTER,
+            src=tpu_buf(job, r, srcs[r], DataType.FLOAT32),
+            dst=BufferInfo(None, block_count(total, n, r), DataType.FLOAT32,
+                           mem_type=MemoryType.TPU),
+            op=ReductionOp.AVG) for r in range(n)]
+        run_xla(job, teams, lambda r: argses[r])
+        expect = np.sum(srcs, axis=0) / n
+        for r in range(n):
+            off = block_offset(total, n, r)
+            np.testing.assert_allclose(
+                np.asarray(argses[r].dst.buffer),
+                expect[off:off + block_count(total, n, r)], rtol=1e-6)
+        text = _lowered(teams[0], CollType.REDUCE_SCATTER,
+                        ReductionOp.AVG, np.float32, total)
+        assert "stablehlo.all_reduce" in text
+        assert "stablehlo.reduce_scatter" not in text
 
 
 class TestXlaOtherColls:

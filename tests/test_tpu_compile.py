@@ -130,6 +130,34 @@ class TestTlXla:
                        for ln in entry)
         assert compiled.memory_analysis().temp_size_in_bytes == 0
 
+    @pytest.mark.parametrize("coll,count,dt", [
+        (CollType.ALLGATHER, 96_337_920, jnp.bfloat16),
+        (CollType.ALLGATHER, 53_896_384, jnp.bfloat16),
+        (CollType.REDUCE_SCATTER, 385_351_680, jnp.float32),
+        (CollType.REDUCE_SCATTER, 215_585_536, jnp.float32)])
+    def test_gather_scatter_flat(self, topo, coll, count, dt):
+        """A bf16 allgather and an fp32 AVG reduce-scatter of the
+        Olmo-Hybrid-7B FSDP2 units (the embedding and a linear-attention
+        block, 4 ranks) run on the flat shard: no relayout loop and no
+        temp beyond one buffer of the input's or the output's size."""
+        from ucc_tpu import CollArgs
+        from ucc_tpu.tl.xla import _build_xla_program
+        n = 4
+        mesh = _mesh(topo, n)
+        args = CollArgs(coll_type=coll, op=ReductionOp.AVG)
+        prog, padded = _build_xla_program(
+            mesh, n, coll, args, np.dtype(dt), count, "xla")
+        assert padded == count
+        x = jax.ShapeDtypeStruct((n * padded,), dt,
+                                 sharding=NamedSharding(mesh, P("r")))
+        compiled = _compile(prog, x)
+        text = compiled.as_text()
+        assert "while(" not in text
+        mem = compiled.memory_analysis()
+        # (a buffer padded to whole tiles: under 1 % more)
+        assert mem.temp_size_in_bytes <= 1.01 * max(
+            mem.argument_size_in_bytes, mem.output_size_in_bytes)
+
 
 class TestAlltoallv:
     """TL/XLA's alltoallv at the ep4 cell's shapes (DeepSeek-V2-Lite,

@@ -716,6 +716,13 @@ class XlaCollTask(CollTask):
             # contribution-less ranks (scatter non-root, barrier, dst-only)
             # deposit typed zero padding
             return np.zeros(1, dtype=self.np_dtype)
+        if self.coll == CollType.ALLGATHER and args.is_inplace:
+            # in place, this rank's block already sits at its offset of dst
+            blk = int(bi.count) // self.tl_team.size
+            lo = self.tl_team.rank * blk
+            flat = bi.buffer.reshape(-1) if bi.mem_type == MemoryType.TPU \
+                else binfo_typed(bi)
+            return flat[lo:lo + blk]
         if bi.mem_type == MemoryType.TPU:
             return bi.buffer    # jax array, stays on device
         return binfo_typed(bi)
@@ -1094,7 +1101,7 @@ def _build_xla_program(mesh, n: int, coll: CollType, args, nd, count: int,
         if isinstance(bi, BufferInfoV) and bi.counts is not None:
             vcounts = [int(c) for c in bi.counts]
 
-    def body_2d(x):       # x: (1, padded) shard-local
+    def compute(x):       # x: (padded,) or its (1, padded) view
         if coll == CollType.ALLREDUCE:
             if alg.startswith("q") and qblock:
                 from ..quant.xla_ops import quant_allreduce
@@ -1135,14 +1142,18 @@ def _build_xla_program(mesh, n: int, coll: CollType, args, nd, count: int,
         raise UccError(Status.ERR_NOT_SUPPORTED,
                        f"tl/xla does not build {coll}")
 
+    # on the flat shard as it arrives: a [1, N] view of a 16-bit dtype tiles
+    # two rows per tile, one of them padding, and costs a relayout on the
+    # way in, another on the way out, and a collective of twice the bytes
+    flat = alg == "xla" and (
+        coll in (CollType.ALLREDUCE, CollType.ALLGATHER) or
+        (coll == CollType.REDUCE_SCATTER and vcounts is None and
+         count % n == 0 and op in (ReductionOp.SUM, ReductionOp.AVG)))
+
     def body(x):          # x: (padded,) flat shard
-        if coll == CollType.ALLREDUCE and alg == "xla":
-            # on the flat shard as it arrives: a [1, N] view of a 16-bit
-            # dtype tiles two rows per tile, one of them padding, and costs
-            # a relayout on the way in, another on the way out, and an
-            # all-reduce of twice the bytes
-            return ops.allreduce(x, op)
-        return body_2d(x[None, :])[0]      # 2-D view inside jit
+        if flat:
+            return compute(x)
+        return compute(x[None, :])[0]      # 2-D view inside jit
 
     # names the program in profiles (``jit_ucc_allreduce_xla``)
     body.__name__ = f"ucc_{coll_type_str(coll)}_{alg}"
