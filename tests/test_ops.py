@@ -46,6 +46,24 @@ class TestOpsInJit:
         assert out.shape == (8, 2)
         np.testing.assert_allclose(np.asarray(out), 8.0)
 
+    @pytest.mark.parametrize("order", [list(range(8)),
+                                       [0, 1, 3, 5, 7, 6, 4, 2]])
+    def test_reduce_scatter_ring_matches_psum_scatter(self, mesh, order):
+        """The ring on 8 ranks, in rank order and in a snake's order, at
+        an odd block (halves of 5 and 4): rank r gets block r."""
+        x = jax.random.normal(jax.random.key(8), (8 * 8 * 9,), jnp.float32)
+
+        def flat(fn):
+            return jax.jit(jax.shard_map(fn, mesh=mesh, in_specs=P("r"),
+                                         out_specs=P("r"),
+                                         check_vma=False))(x)
+        ring = flat(lambda v: ops.reduce_scatter_ring(
+            v, ReductionOp.AVG, order))
+        want = flat(lambda v: ops.reduce_scatter(v, ReductionOp.AVG))
+        assert ring.shape == (8 * 9,)
+        np.testing.assert_allclose(np.asarray(ring), np.asarray(want),
+                                   rtol=1e-5, atol=1e-6)
+
     def test_allgather(self, mesh):
         x = jnp.arange(8 * 2, dtype=jnp.int32).reshape(8, 2)
         out = np.asarray(jax.device_get(run_sm(mesh, ops.allgather, x)))
@@ -171,3 +189,43 @@ class TestOpsAlltoallv:
             [np.arange(counts[i], dtype=np.int32) + 10 * i
              for i in range(n)])
         np.testing.assert_array_equal(out, expect)
+
+
+class _Dev:
+    """A device as ``ops.ring_order`` sees it: its coordinates or none."""
+
+    def __init__(self, coords=None):
+        self.coords = coords
+
+
+class TestRingOrder:
+    """The physical ring that ``reduce_scatter_ring`` travels."""
+
+    @staticmethod
+    def _hops_are_neighbours(devs, order):
+        for a, b in zip(order, order[1:] + order[:1]):
+            ca, cb = devs[a].coords, devs[b].coords
+            assert sum(abs(p - q) for p, q in zip(ca, cb)) == 1, (a, b)
+
+    def test_v5e_2x2(self):
+        # device ids 0-3 of a v5e 2x2 sit at (0,0), (1,0), (0,1), (1,1)
+        devs = [_Dev([x, y, 0]) for y in range(2) for x in range(2)]
+        assert ops.ring_order(devs) == [0, 1, 3, 2]
+
+    @pytest.mark.parametrize("xs,ys", [(2, 4), (4, 2), (3, 4), (4, 4)])
+    def test_snake_over_a_grid(self, xs, ys):
+        devs = [_Dev([x, y, 0]) for y in range(ys) for x in range(xs)]
+        order = ops.ring_order(devs)
+        assert sorted(order) == list(range(xs * ys)) and order[0] == 0
+        self._hops_are_neighbours(devs, order)
+
+    @pytest.mark.parametrize("devs", [
+        [_Dev() for _ in range(4)],                       # the CPU
+        [_Dev([x, y, 0]) for y in range(3) for x in range(3)],   # odd
+        [_Dev([x, 0, 0]) for x in range(4)],              # a line
+        [_Dev([0, 0, 0]) for _ in range(4)]])             # one chip
+    def test_rank_order_without_a_cycle(self, devs):
+        assert ops.ring_order(devs) == list(range(len(devs)))
+
+    def test_cpu_devices(self):
+        assert ops.ring_order(jax.devices()[:4]) == [0, 1, 2, 3]

@@ -361,3 +361,37 @@ def test_alltoallv_plan_and_build_nest_in_the_launch(job4, tmp_path):
     ev = _host_events(cap_.path)
     for child in ev["ucc.xla.a2av.plan"] + ev["ucc.xla.build"]:
         assert _inside(child, ev["ucc.xla.launch"])
+
+
+def test_reduce_scatter_ring_build_nests_in_the_build(job4, tmp_path):
+    """A flat SUM reduce-scatter's program is built as a ring of permutes:
+    one ``ucc.xla.build.ring`` inside its ``ucc.xla.build``, on the
+    program-cache miss only; a MAX reduce-scatter's build holds none."""
+    job, teams = job4
+    n, blk = len(teams), 4099
+
+    def post(op):
+        argses = [CollArgs(
+            coll_type=CollType.REDUCE_SCATTER,
+            src=BufferInfo(_dev(job, r, np.full(n * blk, r + 1.0,
+                                                np.float32)),
+                           n * blk, DataType.FLOAT32,
+                           mem_type=MemoryType.TPU),
+            dst=BufferInfo(None, blk, DataType.FLOAT32,
+                           mem_type=MemoryType.TPU), op=op)
+            for r in range(n)]
+        for rq, a in zip(job.run_coll(teams, lambda r: argses[r]), argses):
+            want = n * (n + 1) / 2 if op == ReductionOp.SUM else n
+            np.testing.assert_array_equal(np.asarray(a.dst.buffer), want)
+            rq.finalize()
+
+    with _Capture(tmp_path) as cap_:
+        post(ReductionOp.SUM)
+        post(ReductionOp.SUM)
+        post(ReductionOp.MAX)
+    tot = profiling.totals()
+    assert tot["ucc.xla.build"][0] == 2
+    assert tot["ucc.xla.build.ring"][0] == 1
+    ev = _host_events(cap_.path)
+    (ring,) = ev["ucc.xla.build.ring"]
+    assert _inside(ring, ev["ucc.xla.build"])

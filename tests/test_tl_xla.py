@@ -276,8 +276,14 @@ class TestXlaGatherScatterProgram:
         text = _lowered(teams[0], coll, op, np_dt, count)
         name = "allgather" if gather else "reduce_scatter"
         assert f"module @jit_ucc_{name}_xla" in text
-        assert f"stablehlo.{'all_gather' if gather else name}" in text
-        assert "stablehlo.reshape" not in text     # no [1, N] view
+        # the reduce-scatter is a ring of permutes (ops.reduce_scatter_ring)
+        assert f"stablehlo.{'all_gather' if gather else 'collective_permute'}" \
+            in text
+        assert "stablehlo.all_reduce" not in text
+        # no [1, N] view of the data (the ring's int32 offset tables may be)
+        assert not any("stablehlo.reshape" in ln and
+                       ("bf16" in ln or "f32" in ln)
+                       for ln in text.splitlines())
 
     def test_reduce_scatter_remainder_falls_back(self, program_job):
         from ucc_tpu.utils.mathutils import block_count, block_offset
@@ -301,6 +307,54 @@ class TestXlaGatherScatterProgram:
         text = _lowered(teams[0], CollType.REDUCE_SCATTER,
                         ReductionOp.AVG, np.float32, total)
         assert "stablehlo.all_reduce" in text
+        assert "stablehlo.reduce_scatter" not in text
+
+
+@pytest.fixture(scope="class", params=[2, 3, 4])
+def ring_job(request):
+    """A job of 2, 3 or 4 ranks (a reduce-scatter has no short-message
+    path: every one runs the compiled program)."""
+    j = UccJob(request.param)
+    try:
+        yield j, j.create_team()
+    finally:
+        j.cleanup()
+
+
+class TestXlaReduceScatterRing:
+    """The flat SUM/AVG reduce-scatter as two rings of permutes, through
+    the TL's post/test path, against numpy: 2, 3 and 4 ranks, 32- and
+    16-bit floats, an even block and an odd one (halves of unequal
+    length)."""
+
+    @pytest.mark.parametrize("blk", [8, 7])
+    @pytest.mark.parametrize("dt,np_dt", [
+        (DataType.BFLOAT16, jnp.bfloat16), (DataType.FLOAT32, np.float32)])
+    @pytest.mark.parametrize("op", [ReductionOp.SUM, ReductionOp.AVG])
+    def test_matches_numpy(self, ring_job, op, dt, np_dt, blk):
+        job, teams = ring_job
+        n = len(teams)
+        total = n * blk
+        rng = np.random.default_rng(n * 100 + blk + int(op))
+        # small ints: every partial sum is exact in bfloat16 too
+        srcs = [rng.integers(-16, 16, total).astype(np_dt) for _ in range(n)]
+        argses = [CollArgs(
+            coll_type=CollType.REDUCE_SCATTER, src=tpu_buf(job, r, srcs[r], dt),
+            dst=BufferInfo(None, blk, dt, mem_type=MemoryType.TPU), op=op)
+            for r in range(n)]
+        run_xla(job, teams, lambda r: argses[r])
+        red = np.stack(srcs).astype(np.float64).sum(0)
+        if op == ReductionOp.AVG:
+            red /= n
+        rtol = 2.0 ** -8 if np_dt == jnp.bfloat16 else 1e-7
+        for r in range(n):
+            out = np.asarray(argses[r].dst.buffer)
+            assert out.dtype == np.dtype(np_dt)
+            np.testing.assert_allclose(out.astype(np.float64),
+                                       red[r * blk:(r + 1) * blk], rtol=rtol)
+        text = _lowered(teams[0], CollType.REDUCE_SCATTER, op, np_dt, total)
+        assert text.count("stablehlo.collective_permute") == 2 * (n - 1)
+        assert "stablehlo.all_reduce" not in text
         assert "stablehlo.reduce_scatter" not in text
 
 

@@ -158,6 +158,43 @@ class TestTlXla:
         assert mem.temp_size_in_bytes <= 1.01 * max(
             mem.argument_size_in_bytes, mem.output_size_in_bytes)
 
+    @pytest.mark.parametrize("count", [385_351_680, 215_585_536,
+                                       185_809_920])
+    def test_reduce_scatter_ring(self, topo, count):
+        """The fp32 AVG reduce-scatter of the Olmo-Hybrid-7B FSDP2 units
+        (embedding, linear- and full-attention block, 4 ranks) is two
+        rings of collective-permutes over the chips' physical ring: no
+        all-reduce, 2(n-1) permutes, every hop between neighbouring
+        chips, and a temp of at most a send and a receive buffer for each
+        direction's half-block (two blocks, half the input shard; the
+        ``psum_scatter`` program's all-reduce holds the whole shard)."""
+        import re
+
+        from ucc_tpu import CollArgs
+        from ucc_tpu.tl.xla import _build_xla_program
+        n = 4
+        mesh = _mesh(topo, n)
+        args = CollArgs(coll_type=CollType.REDUCE_SCATTER, op=ReductionOp.AVG)
+        prog, padded = _build_xla_program(
+            mesh, n, CollType.REDUCE_SCATTER, args, np.dtype(jnp.float32),
+            count, "xla")
+        x = jax.ShapeDtypeStruct((n * padded,), jnp.float32,
+                                 sharding=NamedSharding(mesh, P("r")))
+        compiled = _compile(prog, x)
+        text = compiled.as_text()
+        assert "all-reduce(" not in text
+        assert text.count("collective-permute-start(") == 2 * (n - 1)
+        coords = [tuple(d.coords) for d in mesh.devices.flat]
+        pairs = [tuple(int(v) for v in p) for s in re.findall(
+            r"source_target_pairs=\{(.*?)\}\}", text)
+            for p in re.findall(r"(\d+),(\d+)", s)]
+        assert pairs
+        for a, b in pairs:
+            assert sum(abs(p - q) for p, q in
+                       zip(coords[a], coords[b])) == 1, (a, b)
+        temp = compiled.memory_analysis().temp_size_in_bytes
+        assert temp <= 1.01 * 2 * 4 * count // n      # 1 % for whole tiles
+
 
 class TestAlltoallv:
     """TL/XLA's alltoallv at the ep4 cell's shapes (DeepSeek-V2-Lite,

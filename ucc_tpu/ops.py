@@ -29,6 +29,7 @@ from typing import Optional, Sequence
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 from .constants import ReductionOp
@@ -136,6 +137,83 @@ def allreduce_ring(x, op: ReductionOp = ReductionOp.SUM, axis_name: str = "r"):
     return out.reshape(x.shape[:-1] + (n * blk,))
 
 
+def ring_order(devices) -> list:
+    """The ranks of ``devices`` (rank = position) in a cycle whose every
+    hop, the last back to the first included, is between chips that are
+    neighbours on their ``coords`` grid: a snake over a two-dimensional
+    grid with an even side (a v5e 2x2 gives ``[0, 1, 3, 2]``). Rank order
+    where the devices have no coords (the CPU) or no such cycle exists."""
+    n = len(devices)
+    coords = [tuple(getattr(d, "coords", None) or ()) for d in devices]
+    if n < 3 or not all(coords) or len(set(coords)) < n:
+        return list(range(n))
+    axes = [a for a in range(len(coords[0]))
+            if len({c[a] for c in coords}) > 1]
+    lo = [min(c[a] for c in coords) for a in axes]
+    dims = [max(c[a] for c in coords) - m + 1 for a, m in zip(axes, lo)]
+    if len(axes) != 2 or dims[0] * dims[1] != n or \
+            dims[0] % 2 and dims[1] % 2:
+        return list(range(n))
+    swap = dims[0] % 2 == 1          # snake across the even side
+    X, Y = dims[::-1] if swap else dims
+    # along y = 0, up and down the columns x = X-1 .. 1, back down x = 0
+    cells = [(x, 0) for x in range(X)]
+    for x in range(X - 1, 0, -1):
+        ys = range(1, Y) if (X - 1 - x) % 2 == 0 else range(Y - 1, 0, -1)
+        cells += [(x, y) for y in ys]
+    cells += [(0, y) for y in range(Y - 1, 0, -1)]
+    rank = {tuple(c[a] - m for a, m in zip(axes, lo)): r
+            for r, c in enumerate(coords)}
+    return [rank[(y, x) if swap else (x, y)] for x, y in cells]
+
+
+def reduce_scatter_ring(x, op: ReductionOp, order: Sequence[int],
+                        axis_name: str = "r"):
+    """SUM/AVG reduce-scatter of a flat shard, (total,) -> (total/n,),
+    rank r getting block r, as two rings of ``lax.ppermute`` over
+    ``order`` (the ranks in ring order, ``ring_order``): the first half of
+    every block travels forward and the second half backward, n-1 steps
+    each of a permute and the add of this rank's share of the block. Each
+    link direction carries (n-1)/(2n) of the input, where the all-reduce
+    that ``psum_scatter`` becomes on a v5e carries twice as much and
+    holds an input-sized temp. The local shares are dynamic slices of the
+    flat shard, read in place by the adds. The two rings' receives are
+    tied at every step: left alone, the TPU scheduler runs one ring's
+    permutes after the other's, and each link then idles half the time."""
+    n = len(order)
+    blk = x.shape[-1] // n
+    at = np.empty(n, np.int64)
+    at[list(order)] = np.arange(n)                 # rank -> ring position
+    steps = np.arange(n)[:, None]
+    ring = np.asarray(order)
+    me = lax.axis_index(axis_name)
+    h = (blk + 1) // 2
+    rings = []                                     # (perm, offsets, size)
+    for lo, size, shift in ((0, h, 1), (h, blk - h, -1)):
+        if not size:
+            continue
+        perm = [(int(ring[i]), int(ring[(i + shift) % n])) for i in range(n)]
+        # the block this rank adds to at each step: the one whose owner
+        # the partial reaches after the remaining n-1-s hops
+        owner = ring[(at[None, :] - shift * (steps + 1)) % n]
+        rings.append((perm, jnp.asarray(owner * blk + lo, jnp.int32)[:, me],
+                      size))
+    outs = [lax.dynamic_slice_in_dim(x, offs[0], size)
+            for _, offs, size in rings]
+    for s in range(1, n):
+        got = lax.optimization_barrier(
+            [lax.ppermute(acc, axis_name, perm)
+             for acc, (perm, _, _) in zip(outs, rings)])
+        outs = [g + lax.dynamic_slice_in_dim(x, offs[s], size)
+                for g, (_, offs, size) in zip(got, rings)]
+    out = jnp.concatenate(outs) if len(outs) > 1 else outs[0]
+    if op == ReductionOp.AVG:
+        wide = out.astype(jnp.promote_types(out.dtype, jnp.float32)) / n
+        out = wide.astype(out.dtype) if jnp.issubdtype(
+            out.dtype, jnp.floating) else wide
+    return out
+
+
 def reduce_scatter(x, op: ReductionOp = ReductionOp.SUM, axis_name: str = "r"):
     """x: (..., total) -> (..., total/n), rank r gets block r
     (lax.psum_scatter, tiled)."""
@@ -145,7 +223,7 @@ def reduce_scatter(x, op: ReductionOp = ReductionOp.SUM, axis_name: str = "r"):
         if op == ReductionOp.AVG:
             out = out / axis_size(axis_name)
         return out
-    full = _gather_reduce(x, op, axis_name)
+    full = allreduce(x, op, axis_name)
     n = axis_size(axis_name)
     me = lax.axis_index(axis_name)
     blk = x.shape[-1] // n

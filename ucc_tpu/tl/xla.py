@@ -1129,6 +1129,8 @@ def _build_xla_program(mesh, n: int, coll: CollType, args, nd, count: int,
         if coll == CollType.ALLTOALL:
             return ops.alltoall(x)
         if coll == CollType.REDUCE_SCATTER or coll == CollType.REDUCE_SCATTERV:
+            if ring is not None:
+                return ops.reduce_scatter_ring(x, op, ring)
             if vcounts is None and count % n == 0:
                 return ops.reduce_scatter(x, op)
             # v-counts or a non-divisible total: the equal padded-block
@@ -1149,6 +1151,11 @@ def _build_xla_program(mesh, n: int, coll: CollType, args, nd, count: int,
         coll in (CollType.ALLREDUCE, CollType.ALLGATHER) or
         (coll == CollType.REDUCE_SCATTER and vcounts is None and
          count % n == 0 and op in (ReductionOp.SUM, ReductionOp.AVG)))
+    # a flat SUM/AVG reduce-scatter is two rings of permutes over the
+    # chips' physical ring: the v5e compiler makes psum_scatter an
+    # all-reduce of the whole input plus a slice, twice the wire bytes
+    ring = ops.ring_order(list(mesh.devices.flat)) \
+        if flat and coll == CollType.REDUCE_SCATTER and n > 1 else None
 
     def body(x):          # x: (padded,) flat shard
         if flat:
@@ -1168,9 +1175,12 @@ def _build_xla_program(mesh, n: int, coll: CollType, args, nd, count: int,
     else:
         out_specs = P("r")
 
+    tok = profiling.begin("ucc.xla.build.ring") if ring else None
     program = jax.jit(jax.shard_map(body, mesh=mesh,
                                     in_specs=in_specs,
                                     out_specs=out_specs, check_vma=False))
+    if tok is not None:
+        profiling.end(tok)
     return program, padded
 
 
